@@ -259,8 +259,8 @@ def main(argv=None) -> int:
 
     p = sub.add_parser("kernels", help="static Pallas tile validation")
     p.add_argument("--cache", default=None,
-                   help="tuning-cache path (default: $REPRO_TUNING_CACHE "
-                        "or ~/.cache/repro/tuning.json)")
+                   help="tuning-cache path (default: $REPRO_TUNING_CACHE; "
+                        "unset = shipped tiles only)")
     p.add_argument("--purge", action="store_true",
                    help="remove bad/stale persisted entries")
     p.add_argument("--format", default="text", choices=["text", "json"])

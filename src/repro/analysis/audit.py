@@ -2,7 +2,7 @@
 
 Traces a model's loss (and, by default, its gradient — the Eq. 6 backward
 GEMMs are where FQT lives) to a ClosedJaxpr, walks every ``dot_general``
-through ``scan``/``pjit``/``custom_vjp`` sub-jaxprs (analysis/graph.py),
+through ``scan``/``jit``/``custom_vjp`` sub-jaxprs (analysis/graph.py),
 and diffs what the graph *actually executes* against what
 ``QuantPolicy.resolve(path)`` *declares* for every path in
 ``model_quant_paths(cfg)``:

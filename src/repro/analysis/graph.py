@@ -1,7 +1,7 @@
 """Recursive jaxpr walker: find every GEMM and attribute it to a marker.
 
 ``iter_gemm_sites(closed_jaxpr)`` walks a ClosedJaxpr — recursing through
-``pjit``/``scan``/``while``/``cond``/``custom_vjp``/``remat`` sub-jaxprs —
+``jit``/``scan``/``while``/``cond``/``custom_vjp``/``remat`` sub-jaxprs —
 and yields one :class:`GemmSite` per ``dot_general`` /
 ``conv_general_dilated`` equation, carrying:
 

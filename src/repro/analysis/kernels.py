@@ -2,8 +2,7 @@
 
 Walks every tile source a kernel wrapper can resolve at trace time —
 :data:`~repro.kernels.autotune.SHIPPED_DEFAULTS` plus every entry of the
-persisted tuning cache (``~/.cache/repro/tuning.json`` /
-``$REPRO_TUNING_CACHE``) — and verifies, without compiling anything:
+persisted tuning cache (``$REPRO_TUNING_CACHE``, when set) — and verifies, without compiling anything:
 
   * **VMEM budget**: ``tile_vmem_bytes(bm, bn, bk, kind)`` under
     ``VMEM_BUDGET_BYTES`` for the kernel's family (autotune.KERNEL_SPECS);
@@ -58,7 +57,7 @@ class KernelCheckReport:
     findings: Tuple[KernelFinding, ...]
     n_shipped: int
     n_cache: int
-    cache_file: str
+    cache_file: Optional[str]
 
     @property
     def errors(self) -> Tuple[KernelFinding, ...]:
@@ -68,12 +67,18 @@ class KernelCheckReport:
     def ok(self) -> bool:
         return not self.errors
 
+    def _cache_state(self) -> str:
+        if not self.cache_file:
+            return "no file: $REPRO_TUNING_CACHE unset"
+        if os.path.exists(self.cache_file):
+            return self.cache_file
+        return f"{self.cache_file}, absent"
+
     def format(self, verbose: bool = False) -> str:
         lines = ["== kernel tile check =="]
         lines.append(f"shipped defaults: {self.n_shipped} entries; "
                      f"persisted cache: {self.n_cache} entries "
-                     f"({self.cache_file}"
-                     f"{'' if os.path.exists(self.cache_file) else ', absent'})")
+                     f"({self._cache_state()})")
         shown = [f for f in self.findings
                  if verbose or f.severity != "info"]
         lines.extend(f"  {f}" for f in shown)
@@ -135,7 +140,7 @@ def check_kernels(path: Optional[str] = None) -> KernelCheckReport:
     # entries, which would hide exactly what this checker must report
     import json
     raw: dict = {}
-    if os.path.exists(cache_file):
+    if cache_file and os.path.exists(cache_file):
         try:
             with open(cache_file) as f:
                 loaded = json.load(f)
@@ -180,7 +185,7 @@ def purge_bad_entries(report: KernelCheckReport) -> int:
         return 0
     import json
     raw: dict = {}
-    if os.path.exists(report.cache_file):
+    if report.cache_file and os.path.exists(report.cache_file):
         try:
             with open(report.cache_file) as f:
                 loaded = json.load(f)

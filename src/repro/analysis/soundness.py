@@ -277,7 +277,7 @@ class _Interp:
         if handler is not None:
             handler(eqn, env, ins, full, scans, varies, rand)
             return
-        if prim in ("pjit", "closed_call", "core_call", "remat2",
+        if prim in ("jit", "closed_call", "core_call", "remat2",
                     "checkpoint", "custom_jvp_call", "custom_vjp_call",
                     "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr"):
             self._call_like(eqn, env, ins, full, scans)

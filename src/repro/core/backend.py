@@ -270,7 +270,7 @@ def quantize_sr_rows_qt(x2d: jax.Array, key: jax.Array, bits: int,
     """PSQ stochastic quantize through the fused one-pass kernel.
 
     Bit-identical to ``quantize_psq_stoch(x2d, key, bits)``: both draw the
-    SR uniforms as ``jax.random.bits(key, shape) * 2^-32``.
+    SR uniforms from ``jax.random.bits(key, shape)`` by ``sr_uniform``'s rule.
     """
     rbits = jax.random.bits(key, x2d.shape, jnp.uint32)
     c8, scale, zero = quantize_sr_rows(x2d, rbits, bits,
@@ -376,8 +376,10 @@ def fused_fqt_dx(g2: jax.Array, key: jax.Array, spec, wq: QTensor, *,
     if rbits is None:
         rbits = jax.random.bits(key, g2.shape, jnp.uint32)
     if spec.name == "psq":
-        zg = jnp.min(g2, axis=-1, keepdims=True)
-        sg = B / jnp.maximum(jnp.max(g2, axis=-1, keepdims=True) - zg, _EPS)
+        zg, hg = jax.lax.optimization_barrier(
+            (jnp.min(g2, axis=-1, keepdims=True),
+             jnp.max(g2, axis=-1, keepdims=True)))
+        sg = B / jnp.maximum(hg - zg, _EPS)
     else:                                   # per-tensor PTQ
         zg0, sg0 = _ptq_range(g2, bits)
         zg = jnp.broadcast_to(zg0, (M, 1))

@@ -36,20 +36,6 @@ __all__ = ["compressed_psum", "compressed_grad_allreduce",
 _EPS = 1e-12
 
 
-def _shard_map(body, *, mesh, in_specs, out_specs):
-    """shard_map with replication checks off, across jax versions: newer
-    jax exposes ``jax.shard_map(check_vma=)``, 0.4.x has
-    ``jax.experimental.shard_map.shard_map(check_rep=)``."""
-    import inspect
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    flag = ("check_vma" if "check_vma" in inspect.signature(sm).parameters
-            else "check_rep")
-    return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{flag: False})
-
-
 def _quantize_chunks(x: jax.Array, lo: jax.Array, hi: jax.Array,
                      key: jax.Array, bits: int):
     """Per-chunk affine stochastic quantize; x: (n_chunks, chunk)."""
@@ -120,9 +106,9 @@ def compressed_grad_allreduce(grads, mesh, axis_name: str, key: jax.Array,
             out = compressed_psum(gl, kl[0], axis_name, bits)
             return out / n if mean else out
         spec = P()  # replica view along the compression axis
-        return _shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(spec, P(axis_name)),
-            out_specs=spec)(g, jax.random.split(k, n))
+            out_specs=spec, check_vma=False)(g, jax.random.split(k, n))
 
     leaves, treedef = jax.tree.flatten(grads)
     keys = jax.random.split(key, len(leaves))

@@ -23,7 +23,7 @@ This module draws the machine-checked line between the two:
     (QAT backwards, ``None`` roles, exact-pinned layers).
 
 Markers ride in ``eqn.source_info.name_stack`` and survive ``jax.grad``,
-``custom_vjp``, ``scan``, ``remat``, ``vmap`` and ``pjit`` sub-jaxprs, so the
+``custom_vjp``, ``scan``, ``remat``, ``vmap`` and ``jit`` sub-jaxprs, so the
 auditor can attribute every ``dot_general`` in a full training step without
 any runtime cost — ``named_scope`` is trace-time metadata only.
 """

@@ -27,6 +27,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from ..kernels.tiling import unit_from_bits
+
 __all__ = [
     "QTensor",
     "num_bins",
@@ -103,17 +105,6 @@ class QTensor:
                    zero=jnp.asarray(zero), bits=bits, shape=tuple(shape))
 
 
-def opt_barrier(x):
-    """``jax.lax.optimization_barrier`` that degrades to identity under
-    transforms that can't batch it (jax<0.5 has no vmap rule for the
-    primitive).  The barrier only pins a faster XLA schedule — dropping it
-    is always semantically safe."""
-    try:
-        return jax.lax.optimization_barrier(x)
-    except NotImplementedError:
-        return x
-
-
 def tensor_min_max(x: jax.Array):
     """(min X, max X) in one fused sweep.
 
@@ -128,7 +119,7 @@ def tensor_min_max(x: jax.Array):
     r = x.reshape(-1, x.shape[-1])
     lo = jnp.min(r, axis=-1)
     hi = jnp.max(r, axis=-1)
-    lo, hi = opt_barrier((lo, hi))
+    lo, hi = jax.lax.optimization_barrier((lo, hi))
     return jnp.min(lo), jnp.max(hi)
 
 
@@ -143,17 +134,17 @@ def row_dynamic_range(x2d: jax.Array) -> jax.Array:
     return jnp.max(x2d, axis=-1) - jnp.min(x2d, axis=-1)
 
 
-def sr_uniform(key: jax.Array, shape, dtype=jnp.float32) -> jax.Array:
-    """U[0,1) uniforms for SR, derived as ``random.bits * 2^-32``.
+def sr_uniform(key: jax.Array, shape) -> jax.Array:
+    """f32 U[0,1) uniforms for SR: ``(random.bits >> 8) * 2^-24``.
 
-    This is the ONE convention for SR randomness across the stack: the
-    fused Pallas quantize kernels (kernels/quantize_sr.py) take raw uint32
-    bits and apply the same ``* 2^-32`` inside, so for a given key the
-    ``simulate``/``native`` XLA quantizers and the ``pallas`` kernels emit
-    bit-identical codes.
+    This is the ONE rule for SR randomness across the stack
+    (``kernels.tiling.unit_from_bits``): the Pallas kernels take the raw
+    uint32 ``random.bits(key, shape)`` draw and apply the same rule inside,
+    so for a given key the ``simulate``/``native`` XLA quantizers and the
+    ``pallas`` kernels emit bit-identical codes.  The top 24 bits are exact
+    in f32, so ``u < 1`` strictly.
     """
-    bits = jax.random.bits(key, shape, jnp.uint32)
-    return bits.astype(dtype) * (1.0 / 4294967296.0)
+    return unit_from_bits(jax.random.bits(key, shape, jnp.uint32))
 
 
 def stochastic_round(x: jax.Array, key: jax.Array) -> jax.Array:
@@ -162,8 +153,7 @@ def stochastic_round(x: jax.Array, key: jax.Array) -> jax.Array:
     Implemented as floor(x + u), u ~ U[0,1): E[SR(x)] = x and
     Var[SR(x)] = p(1-p) <= 1/4 (Proposition 4).
     """
-    u = sr_uniform(key, x.shape, x.dtype)
-    return jnp.floor(x + u)
+    return jnp.floor(x + sr_uniform(key, x.shape))
 
 
 def _flatten_rows(x: jax.Array) -> jax.Array:

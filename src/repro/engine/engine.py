@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ..checkpoint import CheckpointManager
-from ..core import QuantPolicy
+from ..core import QuantPolicy, resolve_interpret
 from ..data import Prefetcher, ShardedLoader, make_batch_for
 from ..models import build_model
 from ..optim import Optimizer, adamw, cosine_schedule, sgd
@@ -95,6 +95,12 @@ class Engine:
         self.lr_fn = cosine_schedule(lr, steps,
                                      warmup_steps=max(steps // 20, 1))
 
+        if (mesh is not None and policy.enabled and policy.backend == "pallas"
+                and not resolve_interpret(policy.pallas_interpret)):
+            raise ValueError(
+                "the pallas backend cannot train on a mesh: Mosaic kernels "
+                "are not partitioned by GSPMD; use backend='native' (XLA "
+                "int8) or 'simulate' with mesh=")
         self.mesh = mesh
         self.plan = make_plan(mesh) if mesh is not None else None
         self.abstract_state = abstract_train_state(self.model, self.opt, seed)
@@ -125,10 +131,13 @@ class Engine:
 
     # -- state lifecycle ----------------------------------------------------
     def init_state(self) -> TrainState:
-        state = init_train_state(self.model, self.opt, self.seed)
-        if self.shardings is not None:
-            state = jax.device_put(state, self.shardings)
-        return state
+        def init():
+            return init_train_state(self.model, self.opt, self.seed)
+        if self.shardings is None:
+            return init()
+        # built in place with the plan's shardings: no device ever holds
+        # the whole state (the same values — threefry is partitionable)
+        return jax.jit(init, out_shardings=self.shardings)()
 
     def restore_state(self, step: Optional[int] = None) -> TrainState:
         """Restore the full TrainState (elastic: onto this engine's mesh,

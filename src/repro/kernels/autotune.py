@@ -12,16 +12,18 @@ platform.  This module owns three things:
     (bm, bn, bk) triples under the VMEM budget, and :func:`autotune`, which
     times them through an injectable timer and records the winner;
   * the **persisted cache**: a JSON file keyed
-    ``<kernel>/<MxKxN>/<dtype>/<platform>`` at ``~/.cache/repro/tuning.json``
-    (override with ``$REPRO_TUNING_CACHE``).  Kernel wrappers consult it at
-    trace time via :func:`lookup_tiles`; a missing or corrupt file falls
-    back to :data:`SHIPPED_DEFAULTS` (pre-tuned entries for the bench
-    shapes) and then to the per-kernel default — never an error.
+    ``<kernel>/<MxKxN>/<dtype>/<platform>`` at ``$REPRO_TUNING_CACHE``.
+    Without that variable there is no file: tiles come from what the
+    repository ships.  Kernel wrappers consult it at trace time via
+    :func:`lookup_tiles`; a missing or corrupt file falls back to
+    :data:`SHIPPED_DEFAULTS` (pre-tuned entries for the bench shapes) and
+    then to the per-kernel default — never an error.  Each resolution is
+    recorded with its source in ``get_cache().resolved``.
 
-Re-tune on a new platform/shape with ``python -m benchmarks.bench_kernels
---tune`` (tile choice only changes performance on TPU, where the Pallas
-kernels compile natively; elsewhere the sweep exercises the plumbing and
-the XLA paths ignore the tiles).
+Re-tune on a new platform/shape with ``REPRO_TUNING_CACHE=<file> python -m
+benchmarks.bench_kernels --tune`` (tile choice only changes performance on
+TPU, where the Pallas kernels compile natively; elsewhere the sweep
+exercises the plumbing and the XLA paths ignore the tiles).
 """
 
 from __future__ import annotations
@@ -50,7 +52,6 @@ DEFAULT_TILES: Tiles = (128, 512, 512)
 VMEM_BUDGET_BYTES = 12 * 2 ** 20
 
 ENV_CACHE = "REPRO_TUNING_CACHE"
-_DEFAULT_CACHE_PATH = os.path.join("~", ".cache", "repro", "tuning.json")
 
 # MXU/VPU-aligned sweep axes: bm over the sublane dim (int8 packs 32
 # sublanes; f32 operands need 8), bn/bk over the 128-wide lane dim.
@@ -205,9 +206,11 @@ def cache_key(kernel: str, shape, dtype: str = "int8",
     return f"{kernel}/{shape_key(*shape)}/{dtype}/{platform}"
 
 
-def cache_path() -> str:
-    return os.path.expanduser(os.environ.get(ENV_CACHE)
-                              or _DEFAULT_CACHE_PATH)
+def cache_path() -> Optional[str]:
+    """The persisted cache file, or None when ``$REPRO_TUNING_CACHE`` is
+    unset (then only the shipped tiles apply)."""
+    path = os.environ.get(ENV_CACHE)
+    return os.path.expanduser(path) if path else None
 
 
 # Pre-tuned winners for the bench shapes (keys are platform-agnostic — they
@@ -265,17 +268,21 @@ class TuningCache:
     budget) is DROPPED with a warning, so a stale or hand-edited cache can
     never feed an un-lowerable tile into ``lookup_tiles``.  Entries for
     kernels not in :data:`KERNEL_SPECS` are kept as-is (forward compat;
-    ``python -m repro.analysis kernels`` flags them)."""
+    ``python -m repro.analysis kernels`` flags them).
+
+    ``resolved`` maps ``(kernel, shape, dtype)`` to the ``(tiles, source)``
+    of every :func:`lookup_tiles` call made through this cache."""
 
     def __init__(self, path: Optional[str] = None):
         self.path = path or cache_path()
         self._data: Optional[dict] = None
+        self.resolved: Dict[Tuple[str, str, str], Tuple[Tiles, str]] = {}
 
     def _load(self) -> dict:
         if self._data is not None:
             return self._data
         data: dict = {}
-        if os.path.exists(self.path):
+        if self.path and os.path.exists(self.path):
             try:
                 with open(self.path) as f:
                     raw = json.load(f)
@@ -329,6 +336,9 @@ class TuningCache:
 
     def save(self) -> str:
         """Atomic write (tmp + rename) so a killed tune never corrupts."""
+        if not self.path:
+            raise ValueError(f"no tuning cache file to save to: set "
+                             f"${ENV_CACHE}")
         data = self._load()
         d = os.path.dirname(self.path) or "."
         os.makedirs(d, exist_ok=True)
@@ -362,13 +372,21 @@ def reset_cache() -> None:
 def lookup_tiles(kernel: str, shape, default: Tiles = DEFAULT_TILES,
                  dtype: str = "int8") -> Tiles:
     """Trace-time tile resolution: persisted cache (platform-specific wins
-    over platform-agnostic ``any``) > shipped defaults > ``default``."""
+    over platform-agnostic ``any``) > shipped defaults > ``default``.  The
+    result and its source (``"cache:<key>"``, ``"shipped"`` or
+    ``"default"``) are recorded in ``get_cache().resolved``."""
     cache = get_cache()
-    for platform in (jax.default_backend(), "any"):
-        hit = cache.lookup(cache_key(kernel, shape, dtype, platform))
+    tiles, source = default, "default"
+    shipped = SHIPPED_DEFAULTS.get(f"{kernel}/{shape_key(*shape)}")
+    if shipped is not None:
+        tiles, source = shipped, "shipped"
+    for platform in ("any", jax.default_backend()):
+        key = cache_key(kernel, shape, dtype, platform)
+        hit = cache.lookup(key)
         if hit is not None:
-            return hit
-    return SHIPPED_DEFAULTS.get(f"{kernel}/{shape_key(*shape)}", default)
+            tiles, source = hit, f"cache:{key}"
+    cache.resolved[(kernel, shape_key(*shape), dtype)] = (tiles, source)
+    return tiles
 
 
 def record_tiles(kernel: str, shape, tiles: Tiles,

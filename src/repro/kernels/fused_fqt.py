@@ -26,7 +26,8 @@ Two kernel families cover the three GEMMs of the paper (Eq. 3 / Eq. 6):
                              per-tensor.
 
 Quantization inside the kernels uses the exact formulas of
-``core/quantizers.py`` — ``SR(t) = floor(t + bits * 2^-32)``,
+``core/quantizers.py`` — ``SR(t) = floor(t + u)`` with ``u`` from
+``tiling.unit_from_bits``,
 deterministic ``round(t)`` (round-half-even), ``clip [0, 2^b-1]``, shift
 by ``-2^(b-1)`` — with scales/zeros computed *outside* on the unpadded
 input, so codes are bit-identical to the unfused ``quantize_sr_*`` /
@@ -66,25 +67,14 @@ import jax.experimental.pallas.tpu as pltpu
 from .autotune import lookup_tiles
 from .pack import codes_per_byte, max_safe_k_packed, unpack_tile
 from .tiling import (check_bits, check_tiles, pad2d as _pad2,
-                     pad_rows as _pad_rows, round_up as _round_up)
+                     pad_rows as _pad_rows, round_up as _round_up,
+                     unit_from_bits)
 
 __all__ = [
     "fused_qlhs_matmul", "fused_qlhs_matmul_xla",
     "fused_qboth_tn_matmul", "fused_qboth_tn_matmul_xla",
     "fused_qlhs_packed_matmul", "fused_qlhs_packed_matmul_xla",
 ]
-
-_U32_TO_UNIT = 1.0 / 4294967296.0          # bits * 2^-32, the one SR rule
-
-
-def _opt_barrier(x):
-    # schedule pin only — jax<0.5 can't vmap the primitive, and dropping
-    # the barrier under vmap is always semantically safe
-    try:
-        return jax.lax.optimization_barrier(x)
-    except NotImplementedError:
-        return x
-
 
 # ---------------------------------------------------------------------------
 # LHS-quantizing kernel: forward GEMM and activation-grad GEMM
@@ -107,8 +97,7 @@ def _qlhs_kernel(*refs, nk: int, kdim: int, nbins: float, off: int, bk: int,
     # quantize this (bm, bk) float tile in VMEM — never touches HBM
     t = sa_ref[...] * (xf_ref[...] - za_ref[...])
     if stochastic:
-        u01 = rb_ref[...].astype(jnp.float32) * _U32_TO_UNIT
-        q = jnp.floor(t + u01)
+        q = jnp.floor(t + unit_from_bits(rb_ref[...]))
     else:
         q = jnp.round(t)
     c = jnp.clip(q, 0.0, nbins) - off
@@ -231,7 +220,7 @@ def _qboth_tn_kernel(af_ref, sa_ref, za_ref, bf_ref, sb_ref, zb_ref, rb_ref,
 
     # B: (bk, bn) storage tile of dY, stochastic per-tensor quantize
     tb = sb_ref[0, 0] * (bf_ref[...] - zb_ref[0, 0])
-    u01 = rb_ref[...].astype(jnp.float32) * _U32_TO_UNIT
+    u01 = unit_from_bits(rb_ref[...])
     cb = jnp.clip(jnp.floor(tb + u01), 0.0, nbins_b) - off_b
     row_b = pl.program_id(2) * bk + jax.lax.broadcasted_iota(
         jnp.int32, cb.shape, 0)
@@ -475,12 +464,12 @@ def fused_qlhs_matmul_xla(xf: jax.Array, scale_a: jax.Array,
     if rbits is None:
         q = jnp.round(t)
     else:
-        q = jnp.floor(t + rbits.astype(jnp.float32) * _U32_TO_UNIT)
+        q = jnp.floor(t + unit_from_bits(rbits))
     c = jnp.clip(q, 0.0, nbins) - off
     # materialize the codes exactly once — both the GEMM and the row-sum
     # consume them, and XLA otherwise duplicates the quantize into each
     # consumer fusion (measured ~2% on the large bench shapes)
-    c = _opt_barrier(c)
+    c = jax.lax.optimization_barrier(c)
     dims = (((1,), (1,)) if trans_b else ((1,), (0,))), ((), ())
     acc = _codes_dot(c, y8, dims)
     alpha_a = 1.0 / scale_a                               # (M, 1)
@@ -513,11 +502,11 @@ def fused_qboth_tn_matmul_xla(af: jax.Array, scale_a, zero_a, bf: jax.Array,
     zb = jnp.asarray(zero_b, jnp.float32)
     ca = jnp.clip(jnp.round(sa * (af.astype(jnp.float32) - za)),
                   0.0, nbins_a) - off_a
-    u01 = rbits.astype(jnp.float32) * _U32_TO_UNIT
+    u01 = unit_from_bits(rbits)
     cb = jnp.clip(jnp.floor(sb * (bf.astype(jnp.float32) - zb) + u01),
                   0.0, nbins_b) - off_b
     # single materialization of each code tensor (see fused_qlhs_matmul_xla)
-    ca, cb = _opt_barrier((ca, cb))
+    ca, cb = jax.lax.optimization_barrier((ca, cb))
     acc = _codes_dot(ca, cb, (((0,), (0,)), ((), ())))
     alpha_a = 1.0 / sa
     beta_a = off_a * alpha_a + za
@@ -556,7 +545,7 @@ def fused_qlhs_packed_matmul_xla(xf: jax.Array, scale_a: jax.Array,
     c = jnp.clip(jnp.round(t), 0.0, nbins) - off_a
     w8 = (unpack_tile(packed, wbits)[:K, :] - off_b).astype(jnp.int8)
     # one materialization each (see fused_qlhs_matmul_xla)
-    c, w8 = _opt_barrier((c, w8))
+    c, w8 = jax.lax.optimization_barrier((c, w8))
     acc = _codes_dot(c, w8, (((1,), (0,)), ((), ())))
     alpha_a = 1.0 / scale_a                               # (M, 1)
     beta_a = off_a * alpha_a + zero_a

@@ -42,7 +42,7 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from .autotune import lookup_tiles
-from .fused_fqt import _codes_dot, _opt_barrier
+from .fused_fqt import _codes_dot
 from .pack import codes_per_byte, max_safe_k_packed, unpack_tile
 from .tiling import (check_bits, check_tiles, pad2d as _pad2,
                      round_up as _round_up)
@@ -179,12 +179,12 @@ def _packed_matmul_xla(x8, packed, rs, cs, r2, u, a, b, *, wbits, kdim):
     N = packed.shape[1]
     off = 1 << (wbits - 1)
     w8 = (unpack_tile(packed, wbits)[:kdim, :] - off).astype(jnp.int8)
-    w8 = _opt_barrier(w8)          # one materialization of the unpack chain
+    w8 = jax.lax.optimization_barrier(w8)  # one materialization of the unpack chain
     acc = _codes_dot(x8, w8, (((1,), (0,)), ((), ())))
     # keep the epilogue a separate fusion from the GEMM — mirrors the tile-
     # computation boundary of the Pallas kernel, where the accumulator is
     # materialized in VMEM before the epilogue reads it
-    acc = _opt_barrier(acc)
+    acc = jax.lax.optimization_barrier(acc)
     return (acc * (rs.reshape(M, 1) * cs.reshape(1, N))
             + r2.reshape(M, 1) * u.reshape(1, N)
             + a.reshape(M, 1) + b.reshape(1, N))

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .tiling import (check_bits, pad2d as _pad2, pad2d_edge as _pad2_edge,
-                     round_up as _round_up)
+                     round_up as _round_up, unit_from_bits)
 
 __all__ = ["quantize_sr_rows", "quantize_sr_tensor"]
 
@@ -42,7 +42,7 @@ def _kernel(x_ref, bits_ref, codes_ref, scale_ref, zero_ref, *, B: int):
     scale = B / jnp.maximum(hi - lo, _EPS)           # (bm, 1)
     t = scale * (x - lo)
     # SR(t) = floor(t + u), u ~ U[0,1) from the supplied bits
-    u = bits_ref[...].astype(jnp.float32) * (1.0 / 4294967296.0)
+    u = unit_from_bits(bits_ref[...])
     q = jnp.clip(jnp.floor(t + u), 0.0, B)
     codes_ref[...] = (q - (B + 1) // 2).astype(jnp.int8)   # shifted signed
     scale_ref[...] = scale
@@ -99,7 +99,7 @@ def _tensor_kernel(x_ref, bits_ref, lo_ref, hi_ref, codes_ref, *, B: int):
     x = x_ref[...]
     scale = B / jnp.maximum(hi_ref[0, 0] - lo_ref[0, 0], _EPS)
     t = scale * (x - lo_ref[0, 0])
-    u = bits_ref[...].astype(jnp.float32) * (1.0 / 4294967296.0)
+    u = unit_from_bits(bits_ref[...])
     q = jnp.clip(jnp.floor(t + u), 0.0, B)
     codes_ref[...] = (q - (B + 1) // 2).astype(jnp.int8)
 

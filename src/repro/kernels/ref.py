@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from .tiling import unit_from_bits
+
 __all__ = ["q8_matmul_ref", "quantize_sr_rows_ref", "quantize_sr_tensor_ref"]
 
 _EPS = 1e-12
@@ -17,8 +19,7 @@ def q8_matmul_ref(x8, y8, rs, cs, r2, u, a, b):
 
 
 def _sr(t, rbits):
-    u = rbits.astype(jnp.float32) * (1.0 / 4294967296.0)
-    return jnp.floor(t + u)
+    return jnp.floor(t + unit_from_bits(rbits))
 
 
 def quantize_sr_rows_ref(x, rbits, bits=8):

@@ -31,7 +31,24 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["round_up", "pad2d", "pad2d_edge", "pad_rows", "check_tiles",
-           "check_bits"]
+           "check_bits", "unit_from_bits"]
+
+_TOP24_TO_UNIT = 1.0 / 16777216.0          # 2^-24
+
+
+def unit_from_bits(rbits: jax.Array) -> jax.Array:
+    """U[0,1) f32 uniforms from uint32 ``random.bits``: the one SR rule.
+
+    ``(rbits >> 8) * 2^-24``: the top 24 bits are an integer below 2^24,
+    exact in f32, so ``u <= 1 - 2^-24 < 1`` always.  (Casting the full
+    uint32 to f32 rounds the top 128 patterns up to ``u == 1.0``, and
+    Mosaic cannot lower a uint32 -> f32 cast at all; the int32 hop here
+    lowers on the TPU and in XLA alike.)  Every SR site — the XLA
+    quantizers, the Pallas kernels and their oracles — goes through this
+    function, so codes are bit-identical across backends for a key.
+    """
+    top = jax.lax.shift_right_logical(rbits, jnp.uint32(8))
+    return top.astype(jnp.int32).astype(jnp.float32) * _TOP24_TO_UNIT
 
 
 def round_up(x: int, mult: int) -> int:

@@ -35,10 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# Persistent compilation cache: hillclimb iterations re-lower unchanged cells
-# for free; cache key includes the HLO so edited cells recompile.
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 10)
 # NOTE: rbg PRNG was evaluated for the SR uniforms and REJECTED: on the
 # XLA:CPU AOT backend it blows buffer assignment up ~40x (2 TiB vs 50 GiB
 # temp for minitron train_4k). threefry + loss-chunking is the right config;

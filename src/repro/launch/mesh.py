@@ -8,20 +8,12 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_test_mesh", "mesh_kwargs"]
+__all__ = ["make_production_mesh", "make_test_mesh"]
 
 
-def mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` where the installed jax supports it.
-
-    ``jax.sharding.AxisType`` only exists on newer jax; older releases
-    (e.g. 0.4.x) treat every axis as Auto already, so omitting the kwarg is
-    semantically identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    # every axis Auto: the sharding plan's in/out shardings drive GSPMD
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,9 +22,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     pure DP across the datacenter interconnect."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_test_mesh(data: int = 2, model: int = 4):
     """Small mesh for CPU tests (requires >= data*model fake devices)."""
-    return jax.make_mesh((data, model), ("data", "model"), **mesh_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))

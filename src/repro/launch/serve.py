@@ -29,6 +29,7 @@ from ..configs import get_config
 from ..core import QuantPolicy
 from ..models import build_model
 from ..serve import ServeEngine
+from .device import device_summary, enable_compile_cache
 
 __all__ = ["generate", "main"]
 
@@ -134,9 +135,12 @@ def main(argv=None):
                          "checkpoint instead of random init")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     policy = QuantPolicy.qat(backend=args.backend)  # fwd-only quantization
+    print(f"[serve] {cfg.name} {'smoke' if args.smoke else 'full'} widths; "
+          f"{device_summary(policy)}")
     kv_quant = args.kv_cache == "int8"
     if args.paged and not kv_quant:
         ap.error("--paged requires --kv-cache int8 (pages store the codec)")

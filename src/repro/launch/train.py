@@ -18,6 +18,7 @@ from ..configs import get_config
 from ..core import QuantPolicy
 from ..engine import Engine
 from ..runtime import PreemptionHandler
+from .device import device_summary, enable_compile_cache
 
 __all__ = ["train_loop", "main"]
 
@@ -95,8 +96,10 @@ def parse_mesh(text: str):
 def main(argv=None):
     ap = argparse.ArgumentParser(description="FQT training driver")
     ap.add_argument("--arch", default="statquant-tx")
-    ap.add_argument("--smoke", action="store_true", default=True)
-    ap.add_argument("--full", dest="smoke", action="store_false")
+    ap.add_argument("--smoke", action="store_true", default=True,
+                    help="reduced config (default)")
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="the config's published widths")
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8,
                     help="GLOBAL batch per optimizer step")
@@ -132,6 +135,7 @@ def main(argv=None):
                          "writes (applied before any --override, so CLI "
                          "entries win)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     file_overrides = ()
     if args.override_file:
@@ -158,6 +162,8 @@ def main(argv=None):
                                  backend=args.backend, overrides=overrides)
 
     cfg = get_config(args.arch, smoke=args.smoke)
+    print(f"[train] {cfg.name} {'smoke' if args.smoke else 'full'} widths; "
+          f"{device_summary(policy)}")
     if overrides:
         from ..models import model_quant_paths
         print("[train] resolved per-layer quantizer specs:")

@@ -6,7 +6,7 @@ quantizers, QAT), the forward GEMM and BOTH backward GEMMs run through the
 selected backend and agree with the fp32 ``simulate`` path to fp32
 tolerance — on tile-aligned and ragged (non-tile-multiple) shapes.  The
 quantizer *codes* are bit-identical across backends (shared
-``random.bits * 2^-32`` SR convention), so the only divergence is GEMM
+``(random.bits >> 8) * 2^-24`` SR convention), so the only divergence is GEMM
 accumulation order.
 """
 

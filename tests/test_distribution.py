@@ -62,23 +62,16 @@ print("LOSS", float(mets["loss"]))
 
 def test_compressed_allreduce_unbiased_int8_wire():
     out = run_sub("""
-import inspect
 import jax, jax.numpy as jnp, re
 from jax.sharding import PartitionSpec as P
 from repro.core.compression import compressed_psum
-from repro.launch.mesh import mesh_kwargs
-mesh = jax.make_mesh((8,), ("pod",), **mesh_kwargs(1))
+mesh = jax.make_mesh((8,), ("pod",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 gw = jax.random.normal(jax.random.PRNGKey(0), (8, 33, 7))
 def run(gl, key):
     return compressed_psum(gl[0], key[0], "pod", bits=8)[None] / 8
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map
-params = inspect.signature(shard_map).parameters
-nocheck = ({"check_vma": False} if "check_vma" in params
-           else {"check_rep": False})
-f = jax.jit(shard_map(run, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                      out_specs=P("pod"), **nocheck))
+f = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                          out_specs=P("pod"), check_vma=False))
 ks = jax.random.split(jax.random.PRNGKey(2), 8)
 out = f(gw, ks)
 exact = jnp.mean(gw, axis=0)

@@ -74,6 +74,19 @@ def _engine(batch_fn=None, **kw):
     return Engine(cfg, pol, **args)
 
 
+def test_compiled_pallas_on_a_mesh_is_refused_up_front():
+    """Mosaic kernels are not partitioned by GSPMD: a compiled-Pallas
+    policy on a mesh must fail at construction with a message naming the
+    backends that do train sharded, not deep inside lowering."""
+    from repro.launch.mesh import make_test_mesh
+    pol = QuantPolicy.fqt("bhq", 5, bhq_block=16, backend="pallas",
+                          pallas_interpret=False)
+    with pytest.raises(ValueError, match="cannot train on a mesh"):
+        Engine(get_config("statquant-tx", smoke=True), pol,
+               mesh=make_test_mesh(1, 1), steps=1, batch_size=2,
+               seq_len=8, log_fn=None)
+
+
 def _recording_batch_fn(log):
     cfg = get_config("statquant-tx", smoke=True)
 
@@ -254,8 +267,7 @@ print("FALLBACK OK")
 
 def test_engine_compressed_allreduce_runs():
     """The beyond-paper int8 compressed DP all-reduce composes with the
-    engine step (shard_map inside the jitted, donated, accumulated step) —
-    also covers the jax-version shard_map shim in core/compression.py."""
+    engine step (shard_map inside the jitted, donated, accumulated step)."""
     out = run_sub("""
 import math
 from repro.configs import get_config

@@ -5,7 +5,7 @@ Three layers of evidence:
   * kernel level — the Pallas megakernels (interpret mode) and their XLA
     twins against the *composed* oracle (quantize to a QTensor, int8 GEMM,
     affine epilogue) on ragged shapes.  Tolerances are fp32-roundoff tight:
-    both sides consume bit-identical codes (same ``bits * 2^-32`` SR
+    both sides consume bit-identical codes (same ``(bits >> 8) * 2^-24`` SR
     uniforms), so the only difference is accumulation order.
   * integration level — value + gradient parity of the full ``_fqt``
     custom_vjp under ``fused=True`` across simulate/native/pallas, and a
@@ -325,7 +325,7 @@ def test_quantize_sr_rows_ragged_positive_rows():
                                np.asarray(B / jnp.maximum(hi - lo, 1e-12)),
                                rtol=1e-6)
     t = jnp.asarray(scale).reshape(-1, 1) * (x - lo)
-    u01 = rbits.astype(jnp.float32) * (1.0 / 4294967296.0)
+    u01 = (rbits >> 8).astype(jnp.int32).astype(jnp.float32) / 2.0 ** 24
     want = jnp.clip(jnp.floor(t + u01), 0.0, B) - 128.0
     np.testing.assert_array_equal(np.asarray(c8, dtype=np.int32),
                                   np.asarray(want, dtype=np.int32))
@@ -406,6 +406,28 @@ def test_lookup_precedence(tmp_cache):
     # unknown shape/kernel falls through to the caller's default
     assert at.lookup_tiles("q8_matmul", (7, 7, 7), default=(1, 2, 3)) == \
         (1, 2, 3)
+
+
+def test_unset_cache_serves_shipped_tiles_and_records_source(monkeypatch):
+    """Without $REPRO_TUNING_CACHE no file is read or written: tiles come
+    from what the repository ships, and each lookup records its source."""
+    monkeypatch.delenv(at.ENV_CACHE, raising=False)
+    at.reset_cache()
+    try:
+        assert at.cache_path() is None
+        shape = (512, 1024, 1024)
+        shipped = at.SHIPPED_DEFAULTS["q8_matmul/512x1024x1024"]
+        assert at.lookup_tiles("q8_matmul", shape) == shipped
+        assert at.lookup_tiles("fused_fwd", (7, 7, 7)) == at.DEFAULT_TILES
+        resolved = at.get_cache().resolved
+        assert resolved[("q8_matmul", "512x1024x1024", "int8")] == \
+            (shipped, "shipped")
+        assert resolved[("fused_fwd", "7x7x7", "int8")] == \
+            (at.DEFAULT_TILES, "default")
+        with pytest.raises(ValueError, match=at.ENV_CACHE):
+            at.record_tiles("q8_matmul", shape, (64, 128, 128))
+    finally:
+        at.reset_cache()
 
 
 def test_tile_candidates_respect_budget():

@@ -11,7 +11,7 @@ hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (num_bins, quantize_bhq_stoch, quantize_psq_stoch,
-                        quantize_ptq_det, quantize_ptq_stoch,
+                        quantize_ptq_det, quantize_ptq_stoch, sr_uniform,
                         stochastic_round)
 
 settings.register_profile("ci", max_examples=25, deadline=None)
@@ -75,6 +75,19 @@ def test_stochastic_round_unbiased_and_integer(seed):
     assert bool(jnp.all(jnp.abs(samples - x) < 1.0 + 1e-5))   # adjacent ints
     mean = jnp.mean(samples, 0)
     assert float(jnp.max(jnp.abs(mean - x))) < 0.1            # ~unbiased
+
+
+def test_sr_uniform_strictly_below_one():
+    """The SR rule keeps u in [0, 1) even for the top uint32 patterns
+    (a plain uint32 -> f32 cast rounds those up to exactly 1.0)."""
+    from repro.kernels.tiling import unit_from_bits
+    top = jnp.array([0, 255, 256, 2**32 - 129, 2**32 - 1], jnp.uint32)
+    u = np.asarray(unit_from_bits(top))
+    assert u.dtype == np.float32
+    np.testing.assert_array_equal(
+        u, np.array([0.0, 0.0, 2.0**-24, 1 - 2.0**-24, 1 - 2.0**-24],
+                    np.float32))
+    assert float(jnp.max(sr_uniform(jax.random.PRNGKey(0), (4096,)))) < 1.0
 
 
 @given(st.integers(8, 64), st.integers(2, 16), bits_st, seeds)

@@ -80,3 +80,27 @@ def test_elastic_controller():
     assert not ec.should_rescale(current_dp=15, healthy_chips=240)
     with pytest.raises(RuntimeError):
         ec.plan_mesh(healthy_chips=8)
+
+
+def test_compile_cache_location_and_device_line(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache is one fixed directory in the checkout, used on a TPU
+    only; the launchers' device line says whether Pallas is interpreted."""
+    import os
+
+    import jax
+
+    from repro.core import QuantPolicy
+    from repro.launch.device import (CACHE_DIR, device_summary,
+                                     enable_compile_cache)
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert enable_compile_cache() is None            # this suite runs on CPU
+    assert jax.config.jax_compilation_cache_dir == before
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert CACHE_DIR == os.path.join(root, ".jax_cache")
+    line = device_summary(QuantPolicy.fqt("ptq", 8, backend="pallas"))
+    assert line.startswith("platform=cpu ") and "pallas=interpreted" in line
+    assert "pallas" not in device_summary(QuantPolicy.qat())

@@ -1,0 +1,153 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e.
+
+Interpret mode (every other kernel test) never runs Mosaic, so it cannot
+see what the chip's compiler refuses: an unsupported cast, a slice not
+aligned to the tiling, a block over the VMEM limit.  These tests compile
+each kernel of the training and serving path at ``statquant-tx``'s
+published widths for a v5e chip that is described, not attached — nothing
+runs, so they say nothing about results or times.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU compiler library, and under several pytest
+workers only the worker given this file may do so.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+
+CFG = get_config("statquant-tx")
+D, FF = CFG.d_model, CFG.d_ff
+TOKENS = 4096                       # 8 sequences x 512 tokens per step
+PAGE, PAGES, TABLE_W = 16, 512, 32  # serving pool: 8 lanes x 512 rows
+
+
+@pytest.fixture(scope="module")
+def topo():
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one; keep these compiles out of it
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    specs = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*specs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _w8_coeffs(n):
+    return (jnp.float32(0.01), jnp.float32(0.5), jnp.ones((n,), jnp.float32))
+
+
+F32, I8, U32, I32 = jnp.float32, jnp.int8, jnp.uint32, jnp.int32
+
+
+@pytest.mark.parametrize("role", ["fwd", "dx"])
+def test_fused_qlhs_matmul(one_chip, role):
+    """Forward (deterministic, X @ W) and activation grad (stochastic,
+    per-row PSQ scales, dY @ W.T) of the d_model -> d_ff GEMM."""
+    from repro.kernels.fused_fqt import fused_qlhs_matmul
+    dx = role == "dx"
+    m, k = (TOKENS, FF) if dx else (TOKENS, D)
+
+    def fn(x, s, z, rb, w8):
+        ab, bb, u = _w8_coeffs(D if dx else FF)
+        return fused_qlhs_matmul(x, s, z, rb if dx else None, w8, ab, bb, u,
+                                 bits=5 if dx else 8, trans_b=dx,
+                                 tune_key=f"fused_{role}")
+
+    _compile(fn, one_chip, ((m, k), F32), ((m, 1), F32), ((m, 1), F32),
+             ((m, k), U32), ((D, FF), I8))
+
+
+def test_fused_qboth_tn_matmul(one_chip):
+    """Weight grad X.T @ dY with both operands quantized in the K-sweep."""
+    from repro.kernels.fused_fqt import fused_qboth_tn_matmul
+
+    def fn(x, g, rb, a_vec):
+        return fused_qboth_tn_matmul(x, 0.1, -1.0, g, 0.2, -2.0, rb, a_vec,
+                                     bits_a=8, bits_b=5)
+
+    _compile(fn, one_chip, ((TOKENS, D), F32), ((TOKENS, FF), F32),
+             ((TOKENS, FF), U32), ((D,), F32))
+
+
+@pytest.mark.parametrize("mode", ["rows", "tensor"])
+def test_quantize_sr(one_chip, mode):
+    from repro.kernels import quantize_sr as q
+    fn = q.quantize_sr_rows if mode == "rows" else q.quantize_sr_tensor
+    _compile(lambda x, rb: fn(x, rb, 5), one_chip,
+             ((TOKENS, FF), F32), ((TOKENS, FF), U32))
+
+
+def test_q8_matmul(one_chip):
+    from repro.kernels.q8_matmul import q8_matmul
+    m, k, n = TOKENS, D, FF
+    _compile(q8_matmul, one_chip, ((m, k), I8), ((k, n), I8),
+             ((m,), F32), ((n,), F32), ((m,), F32), ((n,), F32),
+             ((m,), F32), ((n,), F32))
+
+
+def test_kv_gather_pages(one_chip):
+    from repro.kernels.kv_gather import kv_gather_pages
+    flat = CFG.n_kv_heads * CFG.hd
+    _compile(kv_gather_pages, one_chip, ((PAGES, PAGE, flat), I8),
+             ((PAGES, PAGE), F32), ((PAGES, PAGE), F32), ((8, TABLE_W), I32))
+
+
+def test_kv_dequant_rows(one_chip):
+    from repro.kernels.kv_dequant import kv_dequant_rows
+    rows = 8 * PAGE * TABLE_W
+    _compile(kv_dequant_rows, one_chip, ((rows, CFG.n_kv_heads * CFG.hd), I8),
+             ((rows, 1), F32), ((rows, 1), F32))
+
+
+def test_psq_train_step(one_chip):
+    """The whole FQT step with PSQ activation gradients at 4096 tokens.
+
+    The per-row range of fc1's dY feeds the fused dX kernel; without the
+    optimization barrier that pins its min/max reductions, the TPU
+    compiler's bf16-propagation pass crashes the process (SIGILL) on this
+    step.  A crash here takes the test worker down with it."""
+    from repro.core import QuantPolicy
+    from repro.data import make_batch_for
+    from repro.engine import abstract_train_state, make_step_fn
+    from repro.models import build_model
+    from repro.optim import adamw, cosine_schedule
+
+    model, opt = build_model(CFG), adamw()
+    pol = QuantPolicy.fqt("psq", 5, backend="pallas", pallas_interpret=False)
+    step = make_step_fn(model, pol, opt, cosine_schedule(3e-3, 10),
+                        remat=False)
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    state = place(abstract_train_state(model, opt))
+    batch = place(jax.eval_shape(lambda: make_batch_for(CFG, 8, 512)))
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(state, batch).compile()
+    assert "tpu_custom_call" in compiled.as_text()
