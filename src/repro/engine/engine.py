@@ -15,6 +15,15 @@ On restore, the data loader fast-forwards to ``step`` (batches are
 seed-by-step, so the stream continues exactly where it stopped) and the rng
 stream continues from the saved key — a run that is preempted and resumed is
 bit-identical to one that never stopped.
+
+``Engine.run`` opens host spans on the profiler's clock
+(``jax.profiler.TraceAnnotation``), so a device trace says what the host was
+doing in each idle gap: ``repro.engine.batch`` (waiting on the prefetcher),
+``repro.engine.dispatch`` (enqueueing the step), ``repro.engine.drain``
+(blocking on the pending losses), ``repro.engine.checkpoint`` (saving or
+waiting on a save) and ``repro.engine.prefetch`` (starting or stopping the
+prefetch thread).  With no profiler running a span costs about a
+microsecond.
 """
 
 from __future__ import annotations
@@ -24,6 +33,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..checkpoint import CheckpointManager
 from ..core import QuantPolicy, resolve_interpret
@@ -189,22 +199,26 @@ class Engine:
         steps = steps if steps is not None else self.steps
         state = self.state if self.state is not None else self._startup_state()
         start = int(state.step)
-        pf = Prefetcher(self.loader, depth=2, start_step=start)
+        with TraceAnnotation("repro.engine.prefetch"):
+            pf = Prefetcher(self.loader, depth=2, start_step=start)
         history = []                      # (step, float loss)
         pending = []                      # (step, device-scalar loss)
 
         def drain():
             # convert at points that sync anyway, so the steady-state loop
             # never blocks on a loss transfer and buffers don't pile up
-            history.extend((s, float(l)) for s, l in pending)
+            with TraceAnnotation("repro.engine.drain"):
+                history.extend((s, float(l)) for s, l in pending)
             pending.clear()
 
         t0 = time.time()
         try:
             for step in range(start, steps):
                 t_step = time.time()
-                batch = pf.next()
-                state, mets = self.step_fn(state, batch)
+                with TraceAnnotation("repro.engine.batch"):
+                    batch = pf.next()
+                with TraceAnnotation("repro.engine.dispatch"):
+                    state, mets = self.step_fn(state, batch)
                 pending.append((step, mets["loss"]))
                 if self.straggler_probe is not None:
                     self.straggler.record(
@@ -221,21 +235,25 @@ class Engine:
                         f"({time.time()-t0:.1f}s)")
                 if self.ckpt and (step + 1) % self.ckpt_every == 0:
                     drain()
-                    self._save(state)
+                    with TraceAnnotation("repro.engine.checkpoint"):
+                        self._save(state)
                 if self.preemption and self.preemption.should_stop:
                     if self.ckpt:
                         # drain any in-flight async save first — the sync
                         # save path does not, and both write step_<N>.tmp
-                        self.ckpt.wait()
-                        if (step + 1) % self.ckpt_every != 0:
-                            self._save(state, asynchronous=False)
+                        with TraceAnnotation("repro.engine.checkpoint"):
+                            self.ckpt.wait()
+                            if (step + 1) % self.ckpt_every != 0:
+                                self._save(state, asynchronous=False)
                     self.log_fn(f"[engine] preempted at step {step + 1}; "
                                 f"checkpointed")
                     break
         finally:
-            pf.stop()
+            with TraceAnnotation("repro.engine.prefetch"):
+                pf.stop()
             if self.ckpt:
-                self.ckpt.wait()
+                with TraceAnnotation("repro.engine.checkpoint"):
+                    self.ckpt.wait()
             self.state = state
             drain()
         return history

@@ -64,6 +64,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from . import names
 from .autotune import lookup_tiles
 from .pack import codes_per_byte, max_safe_k_packed, unpack_tile
 from .tiling import (check_bits, check_tiles, pad2d as _pad2,
@@ -192,6 +193,7 @@ def fused_qlhs_matmul(xf: jax.Array, scale_a: jax.Array, zero_a: jax.Array,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32),
                         pltpu.VMEM((bm, 1), jnp.int32)],
+        name=names.FUSED_QLHS,
         interpret=interpret,
     )(*operands)
     return out[:M, :N]
@@ -298,6 +300,7 @@ def fused_qboth_tn_matmul(af: jax.Array, scale_a, zero_a, bf: jax.Array,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32),
                         pltpu.VMEM((1, bn), jnp.int32)],
+        name=names.FUSED_QBOTH_TN,
         interpret=interpret,
     )(_pad2(af.astype(jnp.float32), Kp, Mp),
       jnp.asarray(scale_a, jnp.float32).reshape(1, 1),
@@ -418,6 +421,7 @@ def fused_qlhs_packed_matmul(xf: jax.Array, scale_a: jax.Array,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32),
                         pltpu.VMEM((bm, 1), jnp.int32)],
+        name=names.FUSED_QLHS_PACKED,
         interpret=interpret,
     )(_pad2(xf.astype(jnp.float32), Mp, Kp),
       _pad_rows(scale_a.reshape(M, 1), Mp, edge=True),

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import names
 from .autotune import lookup_tiles
 from .tiling import (check_bits, pad2d as _pad2, pad_rows as _pad_rows,
                      round_up as _round_up)
@@ -68,6 +69,7 @@ def _kv_dequant_rows(codes8, scale, zero, *, bits, bm, interpret):
                   pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, Np), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
+        name=names.KV_DEQUANT,
         interpret=interpret,
     )(_pad2(codes8, Mp, Np),
       _pad_rows(scale.reshape(M, 1), Mp, edge=True),

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import names
 from .autotune import lookup_tiles
 from .tiling import check_bits, round_up as _round_up
 
@@ -106,6 +107,7 @@ def _kv_gather_pages(codes, scale, zero, table, *, bits, bm, interpret):
         functools.partial(_kernel, off=1 << (bits - 1)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nb * P, Dp), jnp.float32),
+        name=names.KV_GATHER,
         interpret=interpret,
     )(table.astype(jnp.int32), codes, scale3, zero3)
     return out[:, :, :D]

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from . import names
 from .autotune import lookup_tiles
 from .fused_fqt import _codes_dot
 from .pack import codes_per_byte, max_safe_k_packed, unpack_tile
@@ -149,6 +150,7 @@ def _packed_matmul(x8, packed, rs, cs, r2, u, a, b, *, wbits, bm, bn, bk,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        name=names.PACKED_MATMUL,
         interpret=interpret,
     )(_pad2(x8, Mp, Kp), _pad2(packed, Kp // ppb, Np),
       _pad2(rs.reshape(M, 1), Mp, 1), _pad2(cs.reshape(1, N), 1, Np),

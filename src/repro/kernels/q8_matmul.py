@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from . import names
 from .autotune import lookup_tiles
 from .tiling import (check_tiles, pad2d as _pad2, round_up as _round_up)
 
@@ -109,6 +110,7 @@ def _q8_matmul(x8, y8, rs, cs, r2, u, a, b, *, bm, bn, bk, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        name=names.Q8_MATMUL,
         interpret=interpret,
     )(x8, y8,
       _pad2(rs.reshape(M, 1), Mp, 1), _pad2(cs.reshape(1, N), 1, Np),

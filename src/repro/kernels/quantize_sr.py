@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import names
 from .tiling import (check_bits, pad2d as _pad2, pad2d_edge as _pad2_edge,
                      round_up as _round_up, unit_from_bits)
 
@@ -90,6 +91,7 @@ def _quantize_sr_rows(x, rbits, *, bits, bm, interpret):
         out_shape=[jax.ShapeDtypeStruct((Mp, Np), jnp.int8),
                    jax.ShapeDtypeStruct((Mp, 1), jnp.float32),
                    jax.ShapeDtypeStruct((Mp, 1), jnp.float32)],
+        name=names.QUANTIZE_SR_ROWS,
         interpret=interpret,
     )(xp, rp)
     return codes[:M, :N], scale[:M], zero[:M]
@@ -137,6 +139,7 @@ def _quantize_sr_tensor(x, rbits, *, bits, bm, interpret):
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((bm, Np), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int8),
+        name=names.QUANTIZE_SR_TENSOR,
         interpret=interpret,
     )(_pad2_edge(x, Mp, Np), _pad2(rbits, Mp, Np), lo, hi)
     return codes[:M, :N], B / jnp.maximum(hi[0, 0] - lo[0, 0], _EPS), lo[0, 0]

@@ -170,6 +170,33 @@ def test_straggler_probe_flags_slow_host():
     assert any("stragglers: [3]" in m for m in msgs)
 
 
+def test_run_spans_reach_a_profiler_capture(tmp_path):
+    """Two steps under the profiler: each step's batch wait and dispatch,
+    the drains, the checkpoint save and wait, and the prefetcher's start
+    and stop are host spans of the capture, named ``repro.engine.*``."""
+    import collections
+    import glob
+
+    from jax.profiler import ProfileData
+    eng = _engine(ckpt_dir=str(tmp_path / "ckpt"), ckpt_every=2)
+    eng.run(steps=2)                      # compiles outside the capture
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        eng.run(steps=4)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    spans = collections.Counter(
+        ev.name for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:") for line in plane.lines
+        for ev in line.events if ev.name.startswith("repro.engine."))
+    # drains: the last step's log line, the save at step 3, and the end
+    assert spans == {"repro.engine.batch": 2, "repro.engine.dispatch": 2,
+                     "repro.engine.drain": 3, "repro.engine.checkpoint": 2,
+                     "repro.engine.prefetch": 2}
+
+
 # ---------------------------------------------------------------------------
 # fake-device meshes (subprocesses)
 # ---------------------------------------------------------------------------

@@ -12,6 +12,8 @@ one process may load the TPU compiler library, and under several pytest
 workers only the worker given this file may do so.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -51,11 +53,23 @@ def one_chip(topo):
     cc.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, kernel, *shapes):
+    """Compile ``fn`` and check that the kernel is there under its name:
+    ``pallas_call(name=)`` becomes the HLO instruction's name."""
     specs = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*specs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert kernel in _kernel_calls(compiled.as_text())
     return compiled
+
+
+def _kernel_calls(hlo: str) -> dict:
+    """{kernel name: custom calls} of a compiled module's text."""
+    calls = {}
+    for name in re.findall(r"%([\w\-]+?)(?:\.\d+)? = [^\n]*? custom-call\("
+                           r'[^\n]*custom_call_target="tpu_custom_call"', hlo):
+        calls[name] = calls.get(name, 0) + 1
+    return calls
 
 
 def _w8_coeffs(n):
@@ -79,8 +93,8 @@ def test_fused_qlhs_matmul(one_chip, role):
                                  bits=5 if dx else 8, trans_b=dx,
                                  tune_key=f"fused_{role}")
 
-    _compile(fn, one_chip, ((m, k), F32), ((m, 1), F32), ((m, 1), F32),
-             ((m, k), U32), ((D, FF), I8))
+    _compile(fn, one_chip, "fused_qlhs_matmul", ((m, k), F32), ((m, 1), F32),
+             ((m, 1), F32), ((m, k), U32), ((D, FF), I8))
 
 
 def test_fused_qboth_tn_matmul(one_chip):
@@ -91,22 +105,22 @@ def test_fused_qboth_tn_matmul(one_chip):
         return fused_qboth_tn_matmul(x, 0.1, -1.0, g, 0.2, -2.0, rb, a_vec,
                                      bits_a=8, bits_b=5)
 
-    _compile(fn, one_chip, ((TOKENS, D), F32), ((TOKENS, FF), F32),
-             ((TOKENS, FF), U32), ((D,), F32))
+    _compile(fn, one_chip, "fused_qboth_tn_matmul", ((TOKENS, D), F32),
+             ((TOKENS, FF), F32), ((TOKENS, FF), U32), ((D,), F32))
 
 
 @pytest.mark.parametrize("mode", ["rows", "tensor"])
 def test_quantize_sr(one_chip, mode):
     from repro.kernels import quantize_sr as q
     fn = q.quantize_sr_rows if mode == "rows" else q.quantize_sr_tensor
-    _compile(lambda x, rb: fn(x, rb, 5), one_chip,
+    _compile(lambda x, rb: fn(x, rb, 5), one_chip, f"quantize_sr_{mode}",
              ((TOKENS, FF), F32), ((TOKENS, FF), U32))
 
 
 def test_q8_matmul(one_chip):
     from repro.kernels.q8_matmul import q8_matmul
     m, k, n = TOKENS, D, FF
-    _compile(q8_matmul, one_chip, ((m, k), I8), ((k, n), I8),
+    _compile(q8_matmul, one_chip, "q8_matmul", ((m, k), I8), ((k, n), I8),
              ((m,), F32), ((n,), F32), ((m,), F32), ((n,), F32),
              ((m,), F32), ((n,), F32))
 
@@ -114,24 +128,21 @@ def test_q8_matmul(one_chip):
 def test_kv_gather_pages(one_chip):
     from repro.kernels.kv_gather import kv_gather_pages
     flat = CFG.n_kv_heads * CFG.hd
-    _compile(kv_gather_pages, one_chip, ((PAGES, PAGE, flat), I8),
+    _compile(kv_gather_pages, one_chip, "kv_gather_pages",
+             ((PAGES, PAGE, flat), I8),
              ((PAGES, PAGE), F32), ((PAGES, PAGE), F32), ((8, TABLE_W), I32))
 
 
 def test_kv_dequant_rows(one_chip):
     from repro.kernels.kv_dequant import kv_dequant_rows
     rows = 8 * PAGE * TABLE_W
-    _compile(kv_dequant_rows, one_chip, ((rows, CFG.n_kv_heads * CFG.hd), I8),
-             ((rows, 1), F32), ((rows, 1), F32))
+    _compile(kv_dequant_rows, one_chip, "kv_dequant_rows",
+             ((rows, CFG.n_kv_heads * CFG.hd), I8), ((rows, 1), F32),
+             ((rows, 1), F32))
 
 
-def test_psq_train_step(one_chip):
-    """The whole FQT step with PSQ activation gradients at 4096 tokens.
-
-    The per-row range of fc1's dY feeds the fused dX kernel; without the
-    optimization barrier that pins its min/max reductions, the TPU
-    compiler's bf16-propagation pass crashes the process (SIGILL) on this
-    step.  A crash here takes the test worker down with it."""
+def _compile_step(one_chip, quantizer, **kw):
+    """The whole FQT step of ``statquant-tx`` at 8 x 512 tokens."""
     from repro.core import QuantPolicy
     from repro.data import make_batch_for
     from repro.engine import abstract_train_state, make_step_fn
@@ -139,7 +150,8 @@ def test_psq_train_step(one_chip):
     from repro.optim import adamw, cosine_schedule
 
     model, opt = build_model(CFG), adamw()
-    pol = QuantPolicy.fqt("psq", 5, backend="pallas", pallas_interpret=False)
+    pol = QuantPolicy.fqt(quantizer, 5, backend="pallas",
+                          pallas_interpret=False, **kw)
     step = make_step_fn(model, pol, opt, cosine_schedule(3e-3, 10),
                         remat=False)
 
@@ -149,5 +161,52 @@ def test_psq_train_step(one_chip):
 
     state = place(abstract_train_state(model, opt))
     batch = place(jax.eval_shape(lambda: make_batch_for(CFG, 8, 512)))
-    compiled = jax.jit(step, donate_argnums=(0,)).lower(state, batch).compile()
+    return jax.jit(step, donate_argnums=(0,)).lower(state, batch).compile()
+
+
+def test_psq_train_step(one_chip):
+    """The whole FQT step with PSQ activation gradients at 4096 tokens.
+
+    The per-row range of fc1's dY feeds the fused dX kernel; without the
+    optimization barrier that pins its min/max reductions, the TPU
+    compiler's bf16-propagation pass crashes the process (SIGILL) on this
+    step.  A crash here takes the test worker down with it."""
+    compiled = _compile_step(one_chip, "psq")
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_bhq_train_step_names_kernels_and_markers(one_chip):
+    """The benchmark cell's step (5-bit BHQ, blocks of 256 rows) at 4096
+    tokens: each GEMM kernel is an instruction named after it, once per
+    quantized site of the layer body and once for the head, and its
+    ``op_name`` carries the FQT seam's ``q[path|role]`` marker; the marker
+    reaches BHQ's sorts in the backward, and ``fp[attn.sdpa]`` reaches the
+    attention forward and backward."""
+    hlo = _compile_step(one_chip, "bhq", bhq_block=256).as_text()
+    sites = 7                    # wq wk wv wo fc1 fc2 in the scan, lm_head
+    assert _kernel_calls(hlo) == {"fused_qlhs_matmul": sites,
+                                  "fused_qboth_tn_matmul": sites,
+                                  "q8_matmul": sites}
+    role = {"fused_qlhs_matmul": "fwd", "fused_qboth_tn_matmul": "wgrad",
+            "q8_matmul": "agrad"}
+    for kernel, r in role.items():
+        names = re.findall(rf"%{kernel}(?:\.\d+)? = [^\n]*? custom-call\("
+                           rf'[^\n]*op_name="([^"]*)"', hlo)
+        assert len(names) == sites
+        for name in names:
+            assert re.search(rf"\bq\[[^\]|]+\|{r}\]\)*/.*{kernel}/pallas_call",
+                             name), name
+    # XLA may fuse a kernel with the slice update of its output; the fusion
+    # that runs it takes the kernel's name and op_name
+    for kernel, opcode, name in re.findall(
+            r"%(fused_qlhs_matmul|fused_qboth_tn_matmul|q8_matmul)(?:\.\d+)? = "
+            r'[^\n]*? ([\w\-]+)\([^\n]*op_name="([^"]*)"', hlo):
+        assert opcode in ("custom-call", "fusion"), (kernel, opcode)
+        assert name.endswith(f"/{kernel}/pallas_call"), name
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    sorts = re.findall(r'= [^\n]*? sort\([^\n]*op_name="([^"]*)"', hlo)
+    assert any(re.search(r"transpose\(jvp.*q\[[^\]]+\|agrad\]", n)
+               for n in sorts)
+    for grad in ("jvp()", "transpose(jvp())"):
+        assert any(n.startswith(f"jit(step_fn)/{grad}/")
+                   and "/fp[attn.sdpa]/" in n for n in op_names), grad
