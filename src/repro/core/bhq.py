@@ -11,20 +11,30 @@ The paper's construction, adapted to TPU/XLA static shapes (DESIGN.md Sec. 3):
   4. scale rows by ``diag(s1, s2, ..., s2)`` with the Lagrangian-optimal
      ``s1 ∝ λ1^{-1/3} m^{1/6}``, ``s2 ∝ λ2^{-1/3} m^{1/6}`` (Appendix D.4),
      then apply the group Householder ``Q = I - 2 n nᵀ / ||n||²``,
-     ``n = 1/√m - e1`` — realized as two ``segment_sum``s, never as a matrix;
+     ``n = 1/√m - e1``;
   5. stochastically round with a per-group zero point.
 
-``Q`` is symmetric and involutory, so dequantization applies the *same*
-segment-sum Householder and divides by the row scales: unbiasedness
-``E[Q_b(g)] = g`` holds exactly for any grouping (Theorem 1 requirement).
+Steps 1-3 and the scales of step 4 work on the block's (n,) row statistics
+only: the sort permutes the magnitudes and ranges, never the (n, D) rows,
+and every gather, search and per-group reduction over them is a compare
+against an (n, n) one-hot or same-group mask.  The rows meet the transform
+once, as one dense per-block mixing matrix ``M = Q·S·P`` (P the sort
+permutation) applied with a batched f32 matmul at ``Precision.HIGHEST`` —
+the TPU's default f32 dot is one bf16 pass, a lower precision than the
+quantizer's f32 arithmetic.  ``Q`` is symmetric and involutory, so
+dequantization applies ``M^{-1} = Pᵀ·S^{-1}·Q`` the same way:
+unbiasedness ``E[Q_b(g)] = g`` holds exactly for any grouping (Theorem 1
+requirement).  The mixing costs ``2 * block_rows`` FLOPs per element of the
+operand in each direction (six bf16 passes each at HIGHEST): linear in the
+rows, with no row-wise gathers or scatters at the operand's width.
 
 For large N (LM token rows) the grouping runs independently over row blocks of
-``block_rows`` via ``vmap`` — bounding the sort cost and keeping the paper's
-N≈128-row regime per group search.  Ragged row counts (``n % block_rows != 0``)
-are padded up to the next block multiple with all-zero rows: zero rows sort
-last, carry zero grouping weight, and the per-block transform stays linear and
-invertible, so unbiasedness of the *real* rows is exact; dequantization slices
-the padding back off.
+``block_rows`` via ``vmap`` — bounding the sort and mixing cost and keeping
+the paper's N≈128-row regime per group search.  Ragged row counts
+(``n % block_rows != 0``) are padded up to the next block multiple with
+all-zero rows: zero rows sort last, carry zero grouping weight, and the
+per-block transform stays linear and invertible, so unbiasedness of the
+*real* rows is exact; dequantization slices the padding back off.
 """
 
 from __future__ import annotations
@@ -35,6 +45,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .quantizers import num_bins, stochastic_round, row_dynamic_range
 
@@ -49,9 +60,10 @@ _EPS = 1e-12
 class BHQTensor:
     """Quantized tensor under the block Householder transform.
 
-    Dequantization is ``S^{-1}(codes + Z) = diag(1/s) · Q · (codes + Z)``
-    where ``Q`` is the (involutory) per-group Householder mix.  All fields are
-    flat over ``(n_blocks, block_rows, D)``.
+    Dequantization is ``Pᵀ · diag(1/s) · Q · (codes + Z)`` per block, where
+    ``Q`` is the (involutory) per-group Householder mix and ``Pᵀ`` returns
+    the sorted rows to their original order (:meth:`dequant_map`).  All
+    fields are flat over ``(n_blocks, block_rows, D)``.
     """
 
     codes: jax.Array        # (nb, n, D) uint8 in [0, B]
@@ -71,9 +83,7 @@ class BHQTensor:
 
     def dequant(self) -> jax.Array:
         t = self.codes.astype(jnp.float32) + self.zero
-        y = _apply_householder(t, self.seg, self.n_vec, self.coef)
-        y = y / self.row_scale
-        out = _unpermute(y, self.inv_perm)
+        out = self.dequant_epilogue(t)
         return out.reshape(-1, self.shape[-1])[:self.n_rows].reshape(self.shape)
 
     @property
@@ -85,36 +95,50 @@ class BHQTensor:
     def int8_offset(self) -> int:
         return 1 << (self.bits - 1)
 
+    def dequant_map(self) -> jax.Array:
+        """``M^{-1} = Pᵀ·S^{-1}·Q`` per block, (nb, n, n): original row by
+        sorted row, built from the tensor's own fields."""
+        return jax.vmap(_mixing)(self.inv_perm, self.seg, self.n_vec[..., 0],
+                                 self.coef[..., 0], 1.0 / self.row_scale[..., 0])
+
     def dequant_epilogue(self, t: jax.Array) -> jax.Array:
-        """Apply ``S^{-1}`` + unpermute to ``t`` (same row layout as codes).
+        """Apply ``Pᵀ·S^{-1}·Q`` to ``t`` (same row layout as codes).
 
         Used by the native int8 GEMM path: ``Q_b(g) @ Wᵀ`` is computed as
         ``S^{-1}((codes + Z) @ Wᵀ)`` — the int GEMM runs on raw codes and this
-        O(N·d) VPU epilogue mixes the *output* rows (DESIGN.md Sec. 3).
+        epilogue mixes the *output* rows with one batched matmul
+        (DESIGN.md Sec. 3).
         """
-        y = _apply_householder(t, self.seg, self.n_vec, self.coef)
-        y = y / self.row_scale
-        return _unpermute(y, self.inv_perm)
+        return lax.dot_general(self.dequant_map(), t,
+                               (((2,), (1,)), ((0,), (0,))),
+                               precision=lax.Precision.HIGHEST)
 
 
-def _apply_householder(x: jax.Array, seg: jax.Array, n_vec: jax.Array,
-                       coef: jax.Array) -> jax.Array:
-    """y = Q x per group: y_j = x_j - n_j * coef_g * (nᵀ x)_g, via segment_sum.
+def _pick(mask: jax.Array, v: jax.Array) -> jax.Array:
+    """``sum_k mask[j, k] * v[k]`` per row j: the gather ``v[idx]`` when each
+    row of ``mask`` is one-hot (every other term is an exact zero), a group
+    sum when ``mask`` is a same-group mask.  No gather or scatter."""
+    return jnp.sum(jnp.where(mask, v[None, :], 0), axis=-1)
 
-    Shapes: x (nb, n, D), seg (nb, n), n_vec/coef (nb, n, 1).
+
+def _mixing(perm: jax.Array, seg: jax.Array, n_vec: jax.Array,
+            coef: jax.Array, w: jax.Array) -> jax.Array:
+    """``Pᵀ·diag(w)·Q`` for one block, as an (n, n) array indexed (original
+    row o, sorted row k).
+
+    ``perm`` maps sorted position -> original row, ``Q_jk = δ_jk - c_j n_j
+    n_k`` within a group (0 across groups, ``c`` constant per group, so
+    ``Q`` is symmetric).  Entry ``(o, k)`` is ``w_r Q_rk`` for the sorted
+    position ``r`` of row ``o``.  With ``w = 1/s`` it is the dequantization
+    map; its transpose with ``w = s`` is the quantization map ``Q·S·P``.
     """
-    def one(xb, segb, nb_, cb):
-        n = xb.shape[0]
-        # (nᵀ x)_g = sum_j n_j x_j  per group
-        ntx = jax.ops.segment_sum(nb_ * xb, segb, num_segments=n)  # (n, D)
-        return xb - nb_ * cb * ntx[segb]
-    return jax.vmap(one)(x, seg, n_vec, coef)
-
-
-def _unpermute(x: jax.Array, inv_perm: jax.Array) -> jax.Array:
-    def one(xb, pb):
-        return jnp.zeros_like(xb).at[pb].set(xb)
-    return jax.vmap(one)(x, inv_perm)
+    n = perm.shape[0]
+    at = jnp.arange(n, dtype=perm.dtype)[:, None] == perm[None, :]   # [o, r]
+    u = _pick(at, w * coef * n_vec)          # w_r c_r n_r at original row o
+    seg_o = _pick(at, seg)                   # group of original row o
+    diag = jnp.where(at, w[None, :], 0.0)
+    return diag - jnp.where(seg_o[:, None] == seg[None, :],
+                            u[:, None] * n_vec[None, :], 0.0)
 
 
 def _largest_remainder(weights: jax.Array, total: jax.Array,
@@ -131,9 +155,12 @@ def _largest_remainder(weights: jax.Array, total: jax.Array,
     rem = raw - base
     rem = jnp.where(valid, rem, -1.0)
     short = total - jnp.sum(base)
-    # give +1 to the `short` largest remainders
-    order = jnp.argsort(-rem)
-    rank = jnp.zeros(n, jnp.int32).at[order].set(jnp.arange(n, dtype=jnp.int32))
+    # give +1 to the `short` largest remainders: rank = position in a stable
+    # descending sort of rem, counted as the rows that come before each row
+    idx = jnp.arange(n)
+    before = ((rem[None, :] > rem[:, None])
+              | ((rem[None, :] == rem[:, None]) & (idx[None, :] < idx[:, None])))
+    rank = jnp.sum(before, axis=-1, dtype=jnp.int32)
     return base + jnp.where((rank < short) & valid, 1, 0)
 
 
@@ -216,22 +243,24 @@ def _bhq_transform(g: jax.Array, valid: jax.Array, bits: int, g_search: str):
     into deterministic clipping — a bias, not just variance.
     """
     B = float(num_bins(bits))
-    n, d = g.shape
+    n = g.shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32)
 
     # --- step 1: sort rows by infinity-norm magnitude, descending ----------
+    # only the (n,) row statistics are permuted; the rows themselves meet
+    # the sort once, inside the mixing matrix of step 5
     mag = jnp.max(jnp.abs(g), axis=-1)                       # M_i
     mag = jnp.where(valid, mag, -1.0)                        # pads strictly last
-    perm = jnp.argsort(-mag)                                 # sorted -> original
-    gs = g[perm]
-    mag_s = jnp.maximum(mag[perm], 0.0)
+    perm = jnp.argsort(-mag).astype(jnp.int32)               # sorted -> original
+    sort = perm[:, None] == idx[None, :]                     # one-hot [sorted, orig]
+    mag_s = jnp.maximum(_pick(sort, mag), 0.0)
     n_valid = jnp.sum(valid.astype(jnp.int32))
 
     # --- step 2: choose the number of groups G ------------------------------
-    rng_s = row_dynamic_range(gs)
+    rng_s = _pick(sort, row_dynamic_range(g))
     G = _select_g(mag_s, rng_s, n, g_search, n_valid)        # traced scalar
     G = jnp.minimum(G, n_valid)          # group only among the real rows
 
-    idx = jnp.arange(n, dtype=jnp.int32)
     is_large = idx < G
     is_pad = idx >= n_valid
 
@@ -239,43 +268,42 @@ def _bhq_transform(g: jax.Array, valid: jax.Array, bits: int, g_search: str):
     w = jnp.where(is_large, mag_s, 0.0)
     n_small = jnp.maximum(n_valid - G, 0).astype(jnp.float32)
     extras = _largest_remainder(w, n_small, is_large)
-    # small row p (p = j - G in sorted order) joins group searchsorted(cum, p)
+    # small row p (p = j - G in sorted order) joins group searchsorted(cum, p,
+    # side="right"): the count of cum entries <= p, compared all at once
     cum = jnp.cumsum(extras)                                  # (n,)
     p = jnp.clip(idx - G, 0, n - 1)
-    small_seg = jnp.searchsorted(cum, p, side="right").astype(jnp.int32)
+    small_seg = jnp.sum(cum[None, :] <= p[:, None], axis=-1, dtype=jnp.int32)
     seg = jnp.where(is_large, idx, jnp.clip(small_seg, 0, n - 1))
     seg = jnp.where(is_pad, idx, seg)                         # pads: singletons
+    same = seg[:, None] == seg[None, :]                       # same-group mask
+    own = seg[:, None] == idx[None, :]                        # row -> its group id
 
-    m = (extras + 1).astype(jnp.float32)                      # group sizes (valid < G)
-    m = jnp.maximum(m, 1.0)
-
-    # --- step 4: optimal scales (Appendix D.4) -------------------------------
+    # --- step 4: optimal scales (Appendix D.4), per row of each group --------
     lam1 = jnp.maximum(rng_s, _EPS)                           # per sorted row; rows < G are the large ones
-    lam1_g = jnp.where(is_large, lam1, 1.0)                   # (n,) valid for g < G
+    lam1_g = _pick(own, jnp.where(is_large, lam1, 1.0))       # λ1 of the group's large row
     small_mag = jnp.where(is_large, 0.0, mag_s)
-    lam2_g = 2.0 * jax.ops.segment_max(small_mag, seg, num_segments=n)
+    lam2_g = 2.0 * jnp.max(jnp.where(same, small_mag[None, :], -jnp.inf), axis=-1)
     lam2_g = jnp.maximum(lam2_g, _EPS)
 
-    m_g = jnp.maximum(jax.ops.segment_sum(jnp.ones(n), seg, num_segments=n), 1.0)
+    m_g = jnp.maximum(jnp.sum(same, axis=-1).astype(jnp.float32), 1.0)
     denom = lam1_g ** (2 / 3) * m_g ** (-1 / 3) + lam2_g ** (2 / 3) * m_g ** (2 / 3)
     s1 = B * lam1_g ** (-1 / 3) * m_g ** (1 / 6) / denom
     s2 = B * lam2_g ** (-1 / 3) * m_g ** (1 / 6) / denom
 
-    row_scale = jnp.where(is_large, s1[seg], s2[seg])[:, None]   # (n,1)
+    row_scale = jnp.where(is_large, s1, s2)[:, None]          # (n,1)
 
     # Householder normal: n_j = 1/sqrt(m) - [j is the group's large row]
-    sqrt_m = jnp.sqrt(m_g)[seg]
+    sqrt_m = jnp.sqrt(m_g)
     n_vec = (1.0 / sqrt_m - is_large.astype(jnp.float32))[:, None]
     # 2/||n||² = sqrt(m)/(sqrt(m)-1); zero for singleton groups (Q = I)
-    coef_g = jnp.where(m_g > 1.5, jnp.sqrt(m_g) / jnp.maximum(jnp.sqrt(m_g) - 1.0, _EPS), 0.0)
-    coef = coef_g[seg][:, None]
+    coef = jnp.where(m_g > 1.5, sqrt_m / jnp.maximum(sqrt_m - 1.0, _EPS), 0.0)[:, None]
 
-    # --- step 5: transform + per-group zero ---------------------------------
-    xs = row_scale * gs
-    y = _apply_householder(xs[None], seg[None], n_vec[None], coef[None])[0]
+    # --- step 5: y = Q·S·P·g in one mixing matmul + per-group zero ----------
+    mix = _mixing(perm, seg, n_vec[:, 0], coef[:, 0], row_scale[:, 0])
+    y = lax.dot_general(mix, g, (((0,), (0,)), ((), ())),
+                        precision=lax.Precision.HIGHEST)      # sorted rows
     row_min = jnp.min(y, axis=-1)
-    zero_g = jax.ops.segment_min(row_min, seg, num_segments=n)
-    zero = zero_g[seg][:, None]
+    zero = jnp.min(jnp.where(same, row_min[None, :], jnp.inf), axis=-1)[:, None]
     return y, zero, row_scale, n_vec, coef, seg, perm
 
 
@@ -287,7 +315,7 @@ def _bhq_block(g: jax.Array, key: jax.Array, valid: jax.Array, bits: int,
         g, valid, bits, g_search)
     codes = stochastic_round(y - zero, key)
     codes = jnp.clip(codes, 0.0, B).astype(jnp.uint8)
-    inv_perm = perm  # y rows are in sorted order; scatter back via perm
+    inv_perm = perm  # y rows are in sorted order; dequant_map maps them back
     return codes, zero, row_scale, n_vec, coef, seg, inv_perm
 
 
@@ -362,9 +390,9 @@ def _block_exact_variance(g: jax.Array, retained: jax.Array, *, bits: int,
     s = row_scale[:, 0]
     nv = n_vec[:, 0]
     c = coef[:, 0]
-    ret = retained[perm]                                      # sorted order
-    a = jax.ops.segment_sum(ret * nv ** 2 / s ** 2, seg, num_segments=n)
-    colnorm = ret * (1.0 - 2.0 * c * nv ** 2) / s ** 2 + c ** 2 * nv ** 2 * a[seg]
+    ret = _pick(perm[:, None] == jnp.arange(n)[None, :], retained)   # sorted order
+    a = _pick(seg[:, None] == seg[None, :], ret * nv ** 2 / s ** 2)  # group sums
+    colnorm = ret * (1.0 - 2.0 * c * nv ** 2) / s ** 2 + c ** 2 * nv ** 2 * a
     return jnp.sum(w * colnorm)
 
 

@@ -99,12 +99,28 @@ def test_bhq_roundtrip_and_structure(n, d, bits, seed):
     deq = qt.dequant()
     assert deq.shape == x.shape
     assert bool(jnp.all(jnp.isfinite(deq)))
-    # involution check: applying the Householder transform twice = identity
-    from repro.core.bhq import _apply_householder
-    t = jax.random.normal(jax.random.PRNGKey(seed + 2), qt.codes.shape)
-    once = _apply_householder(t, qt.seg, qt.n_vec, qt.coef)
-    twice = _apply_householder(once, qt.seg, qt.n_vec, qt.coef)
-    assert float(jnp.max(jnp.abs(twice - t))) < 1e-3 * (1 + float(jnp.max(jnp.abs(t))))
+    # the block maps built from the tensor's own fields: M^{-1} M = I, where
+    # M = Q S P (the transpose of P^T S Q), and the Householder Q alone (no
+    # permutation, unit scales) is involutory
+    from repro.core.bhq import _mixing
+    hi = jax.lax.Precision.HIGHEST
+    nv, cf, s = qt.n_vec[..., 0], qt.coef[..., 0], qt.row_scale[..., 0]
+    m = jnp.swapaxes(jax.vmap(_mixing)(qt.inv_perm, qt.seg, nv, cf, s), 1, 2)
+    blk = qt.seg.shape[-1]
+    eye = jnp.eye(blk)
+
+    def near_identity(a, b):
+        # f32 product rounding: |fl(AB) - AB| <= blk * eps * |A| |B|
+        err = jnp.abs(jnp.matmul(a, b, precision=hi) - eye)
+        bound = 4 * blk * float(jnp.finfo(jnp.float32).eps) * jnp.matmul(
+            jnp.abs(a), jnp.abs(b), precision=hi)
+        return bool(jnp.all(err <= bound + 1e-7))
+
+    assert near_identity(qt.dequant_map(), m)
+    ident = jnp.broadcast_to(jnp.arange(blk), qt.seg.shape)
+    q = jax.vmap(_mixing)(ident, qt.seg, nv, cf, jnp.ones_like(s))
+    assert float(jnp.max(jnp.abs(q - jnp.swapaxes(q, 1, 2)))) < 1e-6
+    assert near_identity(q, q)
 
 
 @given(seeds)

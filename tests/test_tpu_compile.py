@@ -175,14 +175,21 @@ def test_psq_train_step(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_bhq_train_step_names_kernels_and_markers(one_chip):
+@pytest.fixture(scope="module")
+def bhq_step_hlo(one_chip):
+    """The benchmark cell's step (5-bit BHQ, blocks of 256 rows) at 4096
+    tokens, compiled once for the tests below."""
+    return _compile_step(one_chip, "bhq", bhq_block=256).as_text()
+
+
+def test_bhq_train_step_names_kernels_and_markers(bhq_step_hlo):
     """The benchmark cell's step (5-bit BHQ, blocks of 256 rows) at 4096
     tokens: each GEMM kernel is an instruction named after it, once per
     quantized site of the layer body and once for the head, and its
     ``op_name`` carries the FQT seam's ``q[path|role]`` marker; the marker
     reaches BHQ's sorts in the backward, and ``fp[attn.sdpa]`` reaches the
     attention forward and backward."""
-    hlo = _compile_step(one_chip, "bhq", bhq_block=256).as_text()
+    hlo = bhq_step_hlo
     sites = 7                    # wq wk wv wo fc1 fc2 in the scan, lm_head
     assert _kernel_calls(hlo) == {"fused_qlhs_matmul": sites,
                                   "fused_qboth_tn_matmul": sites,
@@ -210,3 +217,29 @@ def test_bhq_train_step_names_kernels_and_markers(one_chip):
     for grad in ("jvp()", "transpose(jvp())"):
         assert any(n.startswith(f"jit(step_fn)/{grad}/")
                    and "/fp[attn.sdpa]/" in n for n in op_names), grad
+
+
+def test_bhq_agrad_compiles_to_no_gather_scatter_or_loop(bhq_step_hlo):
+    """BHQ's activation-gradient quantizer, as the chip's compiler leaves
+    it: no scatter or ``while`` under a ``q[...|agrad]`` scope (the step's
+    remaining ones are the embedding gradient and the layer scans), no
+    gather of more than one value per block (the group search picks its
+    candidate count with one), and the per-block mixing matmuls, quantize
+    and inverse at each of the 7 sites, at HIGHEST operand precision."""
+    agrad = [line for line in bhq_step_hlo.splitlines()
+             if re.search(r"\|agrad\]", line)]
+    for op in ("scatter", "while"):
+        hits = [line for line in agrad if re.search(rf"= [^\n]*? {op}\(", line)]
+        assert not hits, hits[:2]
+    for line in agrad:
+        m = re.search(r"= \w+\[([\d,]*)\]\S* gather\(", line)
+        if m:
+            size = 1
+            for dim in filter(None, m.group(1).split(",")):
+                size *= int(dim)
+            assert size <= TOKENS // 256, line
+    mixing = [line for line in agrad
+              if re.search(r"= \w+\[16,256,\d+\]\S* convolution\(", line)]
+    assert len(mixing) >= 2 * 7, len(mixing)
+    for line in mixing:
+        assert "operand_precision={highest,highest}" in line, line
