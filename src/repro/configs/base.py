@@ -43,8 +43,17 @@ class ArchConfig:
     # encoder-decoder (whisper)
     enc_layers: int = 0
     enc_seq: int = 0                 # precomputed frame embeddings length
-    # misc
+    # Granite-style scalars: h0 = embedding_multiplier * E[x]; each residual
+    # branch is scaled by residual_multiplier; attention scores by
+    # attention_multiplier (None: 1/sqrt(hd)); logits divided by
+    # logits_scaling.  The defaults leave a model as it is.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    # the output head is the embedding table, transposed (no lm_head leaf)
     tie_embeddings: bool = False
+    # misc
     vocab_pad_to: int = 256
     dtype: str = "float32"
     source: str = ""                 # provenance tag from the assignment

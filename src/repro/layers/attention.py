@@ -55,12 +55,16 @@ def _qkv(p, x, key, policy, cfg, positions, path="attn"):
     return q, k, v
 
 
-def _sdpa(q, k, v, mask):
-    """q: (B,T,KV,G,hd), k/v: (B,S,KV,hd), mask: broadcast (B,1,1,T,S)."""
+def _sdpa(q, k, v, mask, scale=None):
+    """q: (B,T,KV,G,hd), k/v: (B,S,KV,hd), mask: broadcast (B,1,1,T,S).
+
+    ``scale`` multiplies the scores (``ArchConfig.attention_multiplier``;
+    None: 1/sqrt(hd))."""
     with fp_exempt("attn.sdpa",
                    "attention scores/probs GEMMs stay full precision — the "
                    "paper quantizes only linear layers (Sec. 2.1 setting)"):
-        scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)
+        if scale is None:
+            scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)
         scores = jnp.einsum("btkgh,bskh->bkgts", q * scale, k)
         scores = jnp.where(mask, scores, _NEG)
         probs = jax.nn.softmax(scores.astype(jnp.float32),
@@ -117,7 +121,8 @@ def attention(p: dict, x: jax.Array, key, policy: QuantPolicy,
         mask = mask[None, None, None]
     else:
         mask = jnp.ones((1, 1, 1, T, S), bool)
-    out = _sdpa(q.reshape(B, T, KV, G, hd), k, v, mask)
+    out = _sdpa(q.reshape(B, T, KV, G, hd), k, v, mask,
+                cfg.attention_multiplier)
     out = out.reshape(B, T, H * hd)
     y = dense(p["wo"], out, key, policy, 4, f"{path}.wo")
     if return_kv:
@@ -260,7 +265,8 @@ def decode_attention(p: dict, x: jax.Array, cache: dict, index: jax.Array,
         v = cache["v"].reshape(B, S, KV, hd).astype(x.dtype)
     mask = (jnp.arange(S)[None, :] <= pos[:, None])          # (B, S)
     mask = mask[:, None, None, None, :]                      # (B,1,1,1,S)
-    out = _sdpa(q.reshape(B, 1, KV, G, hd), k, v, mask)
+    out = _sdpa(q.reshape(B, 1, KV, G, hd), k, v, mask,
+                cfg.attention_multiplier)
     y = dense(p["wo"], out.reshape(B, 1, H * hd), key, policy, 4,
               f"{path}.wo")
     return y, cache
@@ -349,7 +355,8 @@ def paged_decode_attention(p: dict, x: jax.Array, pool: dict,
     mask = (jnp.arange(S, dtype=jnp.int32)[None, None, :]
             <= offs[:, :, None])                             # (B, C, S)
     mask = mask[:, None, None]                               # (B,1,1,C,S)
-    out = _sdpa(q.reshape(B, C, KV, G, hd), k, v, mask)
+    out = _sdpa(q.reshape(B, C, KV, G, hd), k, v, mask,
+                cfg.attention_multiplier)
     y = dense(p["wo"], out.reshape(B, C, H * hd), key, policy, 4,
               f"{path}.wo")
     return y, pool

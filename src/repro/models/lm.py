@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from ..core import QuantPolicy
+from ..core import QuantPolicy, fp_exempt
 from ..layers import (apply_norm, attention, decode_attention, dense, embed,
                       init_attention, init_embedding, init_kv_cache,
                       init_kv_cache_quant, init_lm_head, init_mamba2_layer,
@@ -71,8 +71,9 @@ def _init_tx_layer(key, cfg: ArchConfig) -> dict:
 def init_lm_params(key, cfg: ArchConfig) -> dict:
     ke, kl, kh, ks = jax.random.split(key, 4)
     params = {"embed": init_embedding(ke, cfg),
-              "final_norm": init_norm(cfg.d_model, cfg.norm),
-              "lm_head": init_lm_head(kh, cfg)}
+              "final_norm": init_norm(cfg.d_model, cfg.norm)}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = init_lm_head(kh, cfg)
     if cfg.family == "hybrid":
         n_outer = cfg.n_layers // cfg.hybrid_period
         inner = cfg.hybrid_period
@@ -97,6 +98,30 @@ def init_lm_params(key, cfg: ArchConfig) -> dict:
 # Layer application (full-sequence)
 # ---------------------------------------------------------------------------
 
+def _residual(h, y, cfg: ArchConfig):
+    """``h + residual_multiplier * y``, in the residual stream's dtype."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return h + y.astype(h.dtype)
+
+
+def _block(p, h, attend, key, policy, cfg, path, moe_hint=None):
+    """One pre-norm decoder block, the body of training, prefill and both
+    decodes: ``attend`` (normed input -> (output, what the caller keeps:
+    k/v, a cache or a pool)) on one residual branch, the MLP or MoE on the
+    other.  Returns (h, aux loss, kept)."""
+    att, kept = attend(apply_norm(p["ln1"], h, cfg.norm))
+    h = _residual(h, att, cfg)
+    x = apply_norm(p["ln2"], h, cfg.norm)
+    if cfg.moe_experts:
+        y, aux = moe_block(p["moe"], x, key, policy, cfg, moe_hint=moe_hint,
+                           path=f"{path}.moe")
+    else:
+        y, aux = mlp(p["mlp"], x, key, policy, cfg.act,
+                     path=f"{path}.mlp"), 0.0
+    return _residual(h, y, cfg), aux, kept
+
+
 def _tx_layer(p, h, key, policy, cfg, positions, state=None, sdpa_hint=None,
               moe_hint=None, path="layers"):
     """(pre-norm attention + MLP/MoE). state: optional kv dict for prefill.
@@ -105,26 +130,16 @@ def _tx_layer(p, h, key, policy, cfg, positions, state=None, sdpa_hint=None,
     stack shares one trace, so all stacked layers resolve at the same
     ``layers.*`` paths (the hybrid model's shared block uses ``shared.*``).
     """
-    x = apply_norm(p["ln1"], h, cfg.norm)
-    if state is None:
-        att = attention(p["attn"], x, key, policy, cfg, positions,
-                        sdpa_hint=sdpa_hint, path=f"{path}.attn")
-        kv = None
-    else:
+    def attend(x):
+        if state is None:
+            return attention(p["attn"], x, key, policy, cfg, positions,
+                             sdpa_hint=sdpa_hint, path=f"{path}.attn"), None
         att, (k, v) = attention(p["attn"], x, key, policy, cfg, positions,
                                 return_kv=True, sdpa_hint=sdpa_hint,
                                 path=f"{path}.attn")
         B, S = k.shape[0], k.shape[1]
-        kv = {"k": k.reshape(B, S, -1), "v": v.reshape(B, S, -1)}
-    h = h + att.astype(h.dtype)
-    x = apply_norm(p["ln2"], h, cfg.norm)
-    if cfg.moe_experts:
-        y, aux = moe_block(p["moe"], x, key, policy, cfg, moe_hint=moe_hint,
-                           path=f"{path}.moe")
-    else:
-        y, aux = mlp(p["mlp"], x, key, policy, cfg.act,
-                     path=f"{path}.mlp"), 0.0
-    return h + y.astype(h.dtype), aux, kv
+        return att, {"k": k.reshape(B, S, -1), "v": v.reshape(B, S, -1)}
+    return _block(p, h, attend, key, policy, cfg, path, moe_hint)
 
 
 def _forward_seq(params, h, key, policy: QuantPolicy, cfg: ArchConfig,
@@ -224,8 +239,25 @@ def _forward_hybrid(params, h, key, policy, cfg, positions, want_cache,
 
 def _input_embed(params, batch, cfg: ArchConfig):
     if "embeds" in batch:                        # VLM stub frontend
-        return batch["embeds"]
-    return embed(params["embed"], batch["tokens"])
+        h = batch["embeds"]
+    else:
+        h = embed(params["embed"], batch["tokens"])
+    if cfg.embedding_multiplier != 1.0:
+        h = h * cfg.embedding_multiplier
+    return h
+
+
+def _head(params, h, key, policy, cfg: ArchConfig):
+    """Output logits over ``logits_scaling``.  The head GEMM resolves at
+    ``lm_head`` whether its weight is its own leaf or, with tied
+    embeddings, the embedding table transposed — then the table's gradient
+    is the gather's plus the head's."""
+    w = (params["embed"]["table"].T if cfg.tie_embeddings
+         else params["lm_head"]["w"])
+    logits = lm_head({"w": w}, h, key, policy)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _positions(batch, cfg, B, T):
@@ -237,16 +269,23 @@ def _positions(batch, cfg, B, T):
     return pos
 
 
+def _log_likelihood(logits: jax.Array, labels: jax.Array,
+                    vocab_size: int) -> jax.Array:
+    """Per-token log-probability of the label, padding columns masked."""
+    with fp_exempt("lm_head.ce", "the head's log-softmax and cross-entropy "
+                   "are elementwise and reductions, no linear layer"):
+        vp = logits.shape[-1]
+        if vp > vocab_size:
+            neg = jnp.full((vp - vocab_size,), -1e30, logits.dtype)
+            logits = logits.at[..., vocab_size:].set(neg)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        return jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+
+
 def cross_entropy(logits: jax.Array, labels: jax.Array,
                   vocab_size: int) -> jax.Array:
     """Mean next-token CE with padded-vocab masking."""
-    vp = logits.shape[-1]
-    if vp > vocab_size:
-        neg = jnp.full((vp - vocab_size,), -1e30, logits.dtype)
-        logits = logits.at[..., vocab_size:].set(neg)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    return -jnp.mean(_log_likelihood(logits, labels, vocab_size))
 
 
 def _chunk_rows_sharding(act_sharding):
@@ -286,8 +325,8 @@ def chunked_head_loss(params, h, labels, key, policy, cfg,
     y2 = labels.reshape(-1)
     R = h2.shape[0]
     if n_chunks <= 1 or R % n_chunks != 0:
-        logits = lm_head(params["lm_head"], h, key, policy)
-        return cross_entropy(logits, labels, cfg.vocab_size)
+        return cross_entropy(_head(params, h, key, policy, cfg), labels,
+                             cfg.vocab_size)
     hc = h2.reshape(n_chunks, R // n_chunks, d)
     yc = y2.reshape(n_chunks, R // n_chunks)
     rows_sh = _chunk_rows_sharding(act_sharding)
@@ -303,15 +342,9 @@ def chunked_head_loss(params, h, labels, key, policy, cfg,
         # per-chunk fold: Theorem 1 needs the head-grad SR draws independent
         # across chunks — reusing `key` verbatim here made every chunk's
         # quantization noise identical (caught by repro.analysis soundness)
-        logits = lm_head(params["lm_head"], h_c,
-                         jax.random.fold_in(key, c_idx), policy)
-        vp = logits.shape[-1]
-        if vp > cfg.vocab_size:
-            neg = jnp.full((vp - cfg.vocab_size,), -1e30, logits.dtype)
-            logits = logits.at[..., cfg.vocab_size:].set(neg)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        ll = jnp.take_along_axis(logp, y_c[:, None], axis=-1)[:, 0]
-        return acc + jnp.sum(ll), 0
+        logits = _head(params, h_c, jax.random.fold_in(key, c_idx), policy,
+                       cfg)
+        return acc + jnp.sum(_log_likelihood(logits, y_c, cfg.vocab_size)), 0
 
     total, _ = scan_or_loop(body, jnp.float32(0.0),
                             (hc, yc, jnp.arange(n_chunks)), unroll)
@@ -415,24 +448,15 @@ def lm_paged_decode(params, pool, batch, policy: QuantPolicy,
 
     def body(hh, xs):
         lp, pool_l, lk = xs
-        x = apply_norm(lp["ln1"], hh, cfg.norm)
-        att, pool_l = paged_decode_attention(
+        hh, _, pool_l = _block(lp, hh, lambda x: paged_decode_attention(
             lp["attn"], x, pool_l, table, start, lk, policy, cfg,
-            path="layers.attn", kv_quant=kv_quant)
-        hh = hh + att.astype(hh.dtype)
-        x = apply_norm(lp["ln2"], hh, cfg.norm)
-        if cfg.moe_experts:
-            y, _ = moe_block(lp["moe"], x, lk, policy, cfg,
-                             path="layers.moe")
-        else:
-            y = mlp(lp["mlp"], x, lk, policy, cfg.act, path="layers.mlp")
-        return hh + y.astype(hh.dtype), pool_l
+            path="layers.attn", kv_quant=kv_quant), lk, policy, cfg, "layers")
+        return hh, pool_l
     keys = jax.random.split(key, cfg.n_layers)
     h, pools = scan_or_loop(body, h, (params["layers"], pool, keys),
                             cfg.unroll_scan)
     h = apply_norm(params["final_norm"], h, cfg.norm)
-    logits = lm_head(params["lm_head"], h, key, policy)
-    return logits, pools
+    return _head(params, h, key, policy, cfg), pools
 
 
 def lm_prefill(params, batch, policy: QuantPolicy, cfg: ArchConfig,
@@ -456,7 +480,7 @@ def lm_prefill(params, batch, policy: QuantPolicy, cfg: ArchConfig,
     h = apply_norm(params["final_norm"], h, cfg.norm)
     h_last = (h[:, -1:] if last_pos is None
               else h[jnp.arange(B), last_pos][:, None])
-    logits = lm_head(params["lm_head"], h_last, key, policy)
+    logits = _head(params, h_last, key, policy, cfg)
 
     index = jnp.asarray(T, jnp.int32)
     if cfg.family == "hybrid":
@@ -519,14 +543,9 @@ def lm_decode(params, cache, batch, policy: QuantPolicy, cfg: ArchConfig,
                                     cfg.unroll_scan)
             z = dense(fuse, jnp.concatenate([hh, h0], axis=-1), ikeys[-1],
                       policy, 0x70, "layers.fuse")
-            x = apply_norm(shared["ln1"], z, cfg.norm)
-            att, kvc = decode_attention(shared["attn"], x, kvc, index,
-                                        ikeys[-1], policy, cfg,
-                                        path="shared.attn")
-            z = z + att.astype(z.dtype)
-            x = apply_norm(shared["ln2"], z, cfg.norm)
-            z = z + mlp(shared["mlp"], x, ikeys[-1], policy, cfg.act,
-                        path="shared.mlp").astype(z.dtype)
+            z, _, kvc = _block(shared, z, lambda x: decode_attention(
+                shared["attn"], x, kvc, index, ikeys[-1], policy, cfg,
+                path="shared.attn"), ikeys[-1], policy, cfg, "shared")
             hh = hh + z
             return hh, (msts, kvc)
         n_outer = cfg.n_layers // cfg.hybrid_period
@@ -548,24 +567,15 @@ def lm_decode(params, cache, batch, policy: QuantPolicy, cfg: ArchConfig,
     else:
         def body(hh, xs):
             lp, kvc, lk = xs
-            x = apply_norm(lp["ln1"], hh, cfg.norm)
-            att, kvc = decode_attention(lp["attn"], x, kvc, index, lk,
-                                        policy, cfg, path="layers.attn",
-                                        kv_quant=kv_quant)
-            hh = hh + att.astype(hh.dtype)
-            x = apply_norm(lp["ln2"], hh, cfg.norm)
-            if cfg.moe_experts:
-                y, _ = moe_block(lp["moe"], x, lk, policy, cfg,
-                                 path="layers.moe")
-            else:
-                y = mlp(lp["mlp"], x, lk, policy, cfg.act,
-                        path="layers.mlp")
-            return hh + y.astype(hh.dtype), kvc
+            hh, _, kvc = _block(lp, hh, lambda x: decode_attention(
+                lp["attn"], x, kvc, index, lk, policy, cfg,
+                path="layers.attn", kv_quant=kv_quant), lk, policy, cfg,
+                "layers")
+            return hh, kvc
         keys = jax.random.split(key, cfg.n_layers)
         h, kvs = scan_or_loop(body, h, (params["layers"], cache["kv"], keys),
                               cfg.unroll_scan)
         new_cache = {"kv": kvs, "index": index + 1}
 
     h = apply_norm(params["final_norm"], h, cfg.norm)
-    logits = lm_head(params["lm_head"], h, key, policy)
-    return logits, new_cache
+    return _head(params, h, key, policy, cfg), new_cache

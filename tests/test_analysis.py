@@ -63,6 +63,29 @@ def test_audit_families_clean(arch):
     assert report.coverage == 1.0
 
 
+def test_audit_tied_granite_head_resolves_at_lm_head():
+    """Granite's tied head (the embedding table, transposed) is a GEMM like
+    any other: every GEMM goes through the policy, the head's three roles
+    resolve at ``lm_head``, and its loss's ``fp[lm_head.ce]`` scope holds
+    no GEMM."""
+    cfg = get_config("granite-3-2b", smoke=True)
+    assert cfg.tie_embeddings
+    report = audit_model(cfg, FQT8)
+    assert report.ok, report.format()
+    assert report.coverage == 1.0
+    assert {s.role for s in report.sites if s.path == "lm_head"} == {
+        "fwd", "wgrad", "agrad"}
+    assert all(s.kind == "quantized" for s in report.sites
+               if s.path == "lm_head")
+    assert set(report.exemptions) == {"attn.sdpa"}
+    # pinned exact by an override, the tied head is declared full precision
+    exact_head = audit_model(cfg, QuantPolicy.fqt(
+        "bhq", 8, overrides={r"lm_head": "exact"}))
+    assert exact_head.ok, exact_head.format()
+    assert {s.kind for s in exact_head.sites if s.path == "lm_head"} == {
+        "policy_fp"}
+
+
 def test_audit_exact_and_qat():
     cfg = get_config("statquant-tx", smoke=True)
     exact = audit_model(cfg, QuantPolicy.exact())

@@ -49,6 +49,22 @@ def test_engine_step_microbatch_keys_are_sound():
     assert rep.n_streams == rep.n_sr_rounds
 
 
+def test_tied_granite_head_keys_independent():
+    """Granite's tied head draws its SR keys at ``lm_head`` from the step's
+    key, apart from every layer's: each of the 8 quantized sites (7 in the
+    layer body, the head) has its wgrad and agrad scopes, and every SR
+    round its own stream."""
+    cfg = get_config("granite-3-2b", smoke=True)
+    assert cfg.tie_embeddings
+    rep = check_model(cfg, FQT8)
+    assert rep.ok, rep.format(verbose=True)
+    assert rep.n_grad_scopes == 2 * 8
+    assert rep.n_streams == rep.n_sr_rounds
+    step = check_step(cfg, FQT8, accum_steps=2)
+    assert step.ok, step.format(verbose=True)
+    assert step.n_streams == step.n_sr_rounds
+
+
 def test_whisper_self_cross_attention_keys_independent():
     """Regression: the decoder once passed one layer key to both self- and
     cross-attention, whose per-site qkey tags collide — SND002 caught it.

@@ -141,26 +141,28 @@ def test_kv_dequant_rows(one_chip):
              ((rows, 1), F32))
 
 
-def _compile_step(one_chip, quantizer, **kw):
-    """The whole FQT step of ``statquant-tx`` at 8 x 512 tokens."""
+def _compile_step(one_chip, quantizer, cfg=CFG, seq=512, remat=False,
+                  **kw):
+    """The whole FQT step of ``cfg`` (``statquant-tx``) at 8 x ``seq``
+    tokens."""
     from repro.core import QuantPolicy
     from repro.data import make_batch_for
     from repro.engine import abstract_train_state, make_step_fn
     from repro.models import build_model
     from repro.optim import adamw, cosine_schedule
 
-    model, opt = build_model(CFG), adamw()
+    model, opt = build_model(cfg), adamw()
     pol = QuantPolicy.fqt(quantizer, 5, backend="pallas",
                           pallas_interpret=False, **kw)
     step = make_step_fn(model, pol, opt, cosine_schedule(3e-3, 10),
-                        remat=False)
+                        remat=remat)
 
     def place(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one_chip), tree)
 
     state = place(abstract_train_state(model, opt))
-    batch = place(jax.eval_shape(lambda: make_batch_for(CFG, 8, 512)))
+    batch = place(jax.eval_shape(lambda: make_batch_for(cfg, 8, seq)))
     return jax.jit(step, donate_argnums=(0,)).lower(state, batch).compile()
 
 
@@ -243,3 +245,23 @@ def test_bhq_agrad_compiles_to_no_gather_scatter_or_loop(bhq_step_hlo):
     assert len(mixing) >= 2 * 7, len(mixing)
     for line in mixing:
         assert "operand_precision={highest,highest}" in line, line
+
+
+def test_granite_step_fits_one_chip(one_chip):
+    """The step of ``granite2b.train.bhq5``: Granite-3.0-2B at its published
+    widths, 5 of its 40 layers, tied head, 8 x 1024 tokens with remat, 5-bit
+    BHQ on compiled Pallas.  Temporaries plus arguments stay under 15 GiB
+    of the chip's 16, and each GEMM kernel is there at every quantized site
+    (the layer body's 7 and the head).  Remat recomputes the body's forward
+    GEMMs in the backward scan but for ``down``: its output is only the
+    layer's output, which the backward does not need."""
+    import dataclasses
+    cfg = dataclasses.replace(get_config("granite-3-2b"), n_layers=5)
+    compiled = _compile_step(one_chip, "bhq", cfg=cfg, seq=1024, remat=True,
+                             bhq_block=256)
+    mem = compiled.memory_analysis()
+    used = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    assert used < 15 * 2 ** 30, used / 2 ** 30
+    assert _kernel_calls(compiled.as_text()) == {
+        "fused_qlhs_matmul": 7 + 6 + 1, "fused_qboth_tn_matmul": 7 + 1,
+        "q8_matmul": 7 + 1}
