@@ -16,9 +16,11 @@ platform.  This module owns three things:
     Without that variable there is no file: tiles come from what the
     repository ships.  Kernel wrappers consult it at trace time via
     :func:`lookup_tiles`; a missing or corrupt file falls back to
-    :data:`SHIPPED_DEFAULTS` (pre-tuned entries for the bench shapes) and
-    then to the per-kernel default — never an error.  Each resolution is
-    recorded with its source in ``get_cache().resolved``.
+    :data:`SHIPPED_DEFAULTS` (pre-tuned entries for the bench shapes), then
+    to the kernel's shape rule (:data:`SHAPE_RULES`: the weight-gradient
+    kernel's tiles that stream the fewest HBM bytes) and then to the
+    per-kernel default — never an error.  Each resolution is recorded with
+    its source in ``get_cache().resolved``.
 
 Re-tune on a new platform/shape with ``REPRO_TUNING_CACHE=<file> python -m
 benchmarks.bench_kernels --tune`` (tile choice only changes performance on
@@ -28,6 +30,7 @@ exercises the plumbing and the XLA paths ignore the tiles).
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import tempfile
@@ -36,10 +39,13 @@ from typing import Callable, Dict, Iterable, Optional, Tuple
 
 import jax
 
+from .tiling import round_up
+
 __all__ = [
     "DEFAULT_TILES", "SHIPPED_DEFAULTS", "VMEM_BUDGET_BYTES",
-    "KERNEL_SPECS", "validate_entry",
+    "SCOPED_VMEM_BYTES", "KERNEL_SPECS", "SHAPE_RULES", "validate_entry",
     "tile_vmem_bytes", "q8_tile_vmem_bytes", "tile_candidates",
+    "fused_tn_scoped_vmem_bytes", "fused_tn_hbm_bytes", "fused_tn_tiles",
     "shape_key", "cache_key", "cache_path", "TuningCache", "get_cache",
     "reset_cache", "lookup_tiles", "record_tiles", "autotune",
 ]
@@ -50,6 +56,9 @@ DEFAULT_TILES: Tiles = (128, 512, 512)
 
 # Leave ~4 MB of the ~16 MB/core for double-buffered pipelining.
 VMEM_BUDGET_BYTES = 12 * 2 ** 20
+
+# The v5e compiler's default scoped VMEM for one Mosaic kernel.
+SCOPED_VMEM_BYTES = 16 * 2 ** 20
 
 ENV_CACHE = "REPRO_TUNING_CACHE"
 
@@ -99,6 +108,64 @@ def tile_vmem_bytes(bm: int, bn: int, bk: int, kind: str = "q8") -> int:
     raise ValueError(f"unknown kernel kind {kind!r}; expected one of "
                      f"('q8', 'fused_lhs', 'fused_tn', 'packed', "
                      f"'fused_packed')")
+
+
+def fused_tn_scoped_vmem_bytes(bm: int, bn: int, bk: int) -> int:
+    """Scoped VMEM that Mosaic allocates for the weight-gradient kernel: two
+    buffers each of the f32 A (bk, bm) block, the f32 B and uint32 bits
+    (bk, bn) blocks and the f32 (bm, bn) output block, the int32
+    accumulator, and 1 MiB for the small blocks (the (bm, 1) row vector,
+    scalars, column sums).  At or above what the v5e compiler reports for
+    each tile the shape rule picks for the benchmark's shapes."""
+    return (2 * (4 * bk * bm + 8 * bk * bn + 4 * bm * bn) + 4 * bm * bn
+            + 2 ** 20)
+
+
+def fused_tn_hbm_bytes(m: int, k: int, n: int, tiles: Tiles) -> int:
+    """HBM bytes the weight-gradient kernel's grid ``(M/bm, N/bn, K/bk)``
+    streams: the f32 A operand (4 B an element) once per bn-column stripe,
+    the f32 B operand and its uint32 SR bits (8 B) once per bm-row stripe."""
+    bm, bn, _ = tiles
+    mp, np_ = round_up(m, 128), round_up(n, 128)
+    return 4 * k * mp * -(-np_ // bn) + 8 * k * np_ * -(-mp // bm)
+
+
+@functools.lru_cache(maxsize=256)
+def fused_tn_tiles(m: int, k: int, n: int) -> Tiles:
+    """The ``fused_dw`` tiles from the shape: the fewest HBM bytes
+    (:func:`fused_tn_hbm_bytes`) under the VMEM budget, whose real footprint
+    (:func:`fused_tn_scoped_vmem_bytes`) also fits the chip's default scoped
+    VMEM — a kernel's own ``vmem_limit_bytes`` no longer holds once XLA
+    fuses the call into its consumer, as the layer scan does when it writes
+    each weight gradient into its stacked buffer.
+
+    bm and bn range over multiples of 128 up to the rounded-up dims; bk is
+    the largest of 128, 256, ... (MXU depth; a multiple of 32 for the int8
+    A tile) that fits beside them.  Ties go to fewer grid steps, then to
+    less work in the edge blocks, then to the larger bm: fewer passes over
+    the operand that is stochastically rounded.
+    """
+    mp, np_, kp = round_up(m, 128), round_up(n, 128), round_up(k, 128)
+    bks = [bk for bk in (128, 256, 512, 1024, 2048) if bk <= kp]
+    best, best_key = None, None
+    for bm in range(128, mp + 1, 128):
+        for bn in range(128, np_ + 1, 128):
+            fits = [bk for bk in bks
+                    if tile_vmem_bytes(bm, bn, bk, "fused_tn")
+                    <= VMEM_BUDGET_BYTES
+                    and fused_tn_scoped_vmem_bytes(bm, bn, bk)
+                    <= SCOPED_VMEM_BYTES]
+            if not fits:
+                break                     # a wider bn fits no better
+            tiles = (bm, bn, fits[-1])
+            # the output the grid covers, edge blocks whole: with the grid
+            # steps it breaks ties in bytes toward less wasted work
+            area = round_up(mp, bm) * round_up(np_, bn)
+            steps = area // (bm * bn) * round_up(kp, fits[-1]) // fits[-1]
+            key = (fused_tn_hbm_bytes(m, k, n, tiles), steps, area, -bm)
+            if best_key is None or key < best_key:
+                best, best_key = tiles, key
+    return best
 
 
 def q8_tile_vmem_bytes(bm: int, bn: int, bk: int, fused: bool = False) -> int:
@@ -174,7 +241,6 @@ def tile_candidates(m: int, k: int, n: int, kind: str = "q8",
                     budget: int = VMEM_BUDGET_BYTES) -> Tuple[Tiles, ...]:
     """MXU-aligned (bm, bn, bk) triples under the VMEM budget, no larger
     than the (rounded-up) problem dims — the autotuner's sweep space."""
-    from .tiling import round_up
     out = []
     for bm in _BM_CANDIDATES:
         if bm > round_up(m, 32):
@@ -225,15 +291,12 @@ SHIPPED_DEFAULTS: Dict[str, Tiles] = {
     "fused_fwd/512x1024x1024": (128, 512, 512),
     "fused_fwd/1024x4096x1024": (128, 512, 512),
     "fused_fwd/4096x1024x4096": (128, 1024, 512),
-    # dx/dw keys are the GEMM-logical (M, K, N) the wrappers look up —
-    # for a model GEMM (m, k, n): dx contracts n -> (m, n, k); dw contracts
-    # m -> (k, m, n)
+    # dx keys are the GEMM-logical (M, K, N) the wrapper looks up — for a
+    # model GEMM (m, k, n), dx contracts n -> (m, n, k); fused_dw tiles
+    # come from its shape rule (SHAPE_RULES)
     "fused_dx/512x1024x1024": (128, 512, 512),
     "fused_dx/1024x1024x4096": (128, 512, 512),
     "fused_dx/4096x4096x1024": (128, 1024, 512),
-    "fused_dw/1024x512x1024": (128, 512, 256),
-    "fused_dw/4096x1024x1024": (128, 512, 256),
-    "fused_dw/1024x4096x4096": (128, 512, 256),
     "kv_dequant/rows": (256, 0, 0),
     "kv_gather/rows": (256, 0, 0),
     # packed-weight family: the int32 unpack intermediate (4*bk*bn) is the
@@ -245,6 +308,13 @@ SHIPPED_DEFAULTS: Dict[str, Tiles] = {
     "fused_packed/512x1024x1024": (128, 512, 512),
     "fused_packed/1024x4096x1024": (128, 512, 512),
     "fused_packed/4096x1024x4096": (128, 512, 512),
+}
+
+
+# Kernels whose tiles, where nothing is shipped or cached for the shape,
+# are computed from it (resolved with source "rule").
+SHAPE_RULES: Dict[str, Callable[[int, int, int], Tiles]] = {
+    "fused_dw": fused_tn_tiles,
 }
 
 
@@ -372,11 +442,15 @@ def reset_cache() -> None:
 def lookup_tiles(kernel: str, shape, default: Tiles = DEFAULT_TILES,
                  dtype: str = "int8") -> Tiles:
     """Trace-time tile resolution: persisted cache (platform-specific wins
-    over platform-agnostic ``any``) > shipped defaults > ``default``.  The
-    result and its source (``"cache:<key>"``, ``"shipped"`` or
-    ``"default"``) are recorded in ``get_cache().resolved``."""
+    over platform-agnostic ``any``) > shipped defaults > the kernel's shape
+    rule (:data:`SHAPE_RULES`) > ``default``.  The result and its source
+    (``"cache:<key>"``, ``"shipped"``, ``"rule"`` or ``"default"``) are
+    recorded in ``get_cache().resolved``."""
     cache = get_cache()
     tiles, source = default, "default"
+    rule = SHAPE_RULES.get(kernel)
+    if rule is not None:
+        tiles, source = rule(*shape), "rule"
     shipped = SHIPPED_DEFAULTS.get(f"{kernel}/{shape_key(*shape)}")
     if shipped is not None:
         tiles, source = shipped, "shipped"

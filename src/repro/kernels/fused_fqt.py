@@ -47,11 +47,13 @@ Tile shapes come from the persisted autotuner cache
 (``kernels/autotune.py``) unless given explicitly; bad explicit tiles fail
 fast in ``check_tiles`` with the shape and tile in the message.
 
-Padding: float operands and epilogue vectors are zero-padded to tile
-multiples; in-kernel masks zero the *codes* of padded contraction
-rows/cols (``k*bk + iota < kdim``) so the accumulator and the row/col-sum
-scratches only ever see real data.  Output rows/cols beyond the real shape
-are sliced off by the wrapper.
+Padding: the LHS kernels zero-pad float operands and epilogue vectors to
+tile multiples and slice the output back.  The weight-gradient kernel pads
+nothing: its grid runs partial edge blocks over the unpadded operands and
+writes back only the real (M, N).  Either way in-kernel masks zero the
+*codes* of contraction rows/cols at or beyond the real K
+(``k*bk + iota < kdim``), so the accumulator and the row/col-sum scratches
+only ever see real data.
 """
 
 from __future__ import annotations
@@ -278,16 +280,18 @@ def fused_qboth_tn_matmul(af: jax.Array, scale_a, zero_a, bf: jax.Array,
     bk = min(bk, _round_up(K, 8))
     check_tiles("fused_qboth_tn_matmul", (M, K, N), (bm, bn, bk),
                 interpret=interpret, multiples=(128, 128, 8))
-    Mp, Np, Kp = _round_up(M, bm), _round_up(N, bn), _round_up(K, bk)
-    nk = Kp // bk
+    nk = pl.cdiv(K, bk)
     scalar = lambda i, j, k: (0, 0)
-    out = pl.pallas_call(
+    # no operand is padded in HBM: the grid's edge blocks run past the
+    # arrays, the kernel zeroes the codes of rows at or beyond K, and
+    # output rows/cols beyond (M, N) are never written back
+    return pl.pallas_call(
         functools.partial(
             _qboth_tn_kernel, nk=nk, kdim=K,
             nbins_a=float((1 << bits_a) - 1), off_a=1 << (bits_a - 1),
             nbins_b=float((1 << bits_b) - 1), off_b=1 << (bits_b - 1),
             bk=bk),
-        grid=(Mp // bm, Np // bn, nk),
+        grid=(pl.cdiv(M, bm), pl.cdiv(N, bn), nk),
         in_specs=[
             pl.BlockSpec((bk, bm), lambda i, j, k: (k, i)),
             pl.BlockSpec((1, 1), scalar), pl.BlockSpec((1, 1), scalar),
@@ -297,20 +301,18 @@ def fused_qboth_tn_matmul(af: jax.Array, scale_a, zero_a, bf: jax.Array,
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32),
                         pltpu.VMEM((1, bn), jnp.int32)],
         name=names.FUSED_QBOTH_TN,
         interpret=interpret,
-    )(_pad2(af.astype(jnp.float32), Kp, Mp),
+    )(af.astype(jnp.float32),
       jnp.asarray(scale_a, jnp.float32).reshape(1, 1),
       jnp.asarray(zero_a, jnp.float32).reshape(1, 1),
-      _pad2(bf.astype(jnp.float32), Kp, Np),
+      bf.astype(jnp.float32),
       jnp.asarray(scale_b, jnp.float32).reshape(1, 1),
       jnp.asarray(zero_b, jnp.float32).reshape(1, 1),
-      _pad2(rbits, Kp, Np),
-      _pad2(a_vec.reshape(M, 1), Mp, 1))
-    return out[:M, :N]
+      rbits, a_vec.reshape(M, 1))
 
 
 # ---------------------------------------------------------------------------

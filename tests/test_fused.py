@@ -16,6 +16,7 @@ Three layers of evidence:
     tmpdir cache, including corrupt-cache fallback and lookup precedence.
 """
 
+import functools
 import importlib
 import json
 
@@ -39,6 +40,10 @@ from repro.kernels.quantize_sr import quantize_sr_rows
 from repro.kernels.tiling import pad2d_edge
 
 RAGGED = [(33, 17, 9), (64, 128, 32)]
+# the weight gradient of (40, 200, 300) is the kernel's (M, K, N) =
+# (200, 40, 300): its shape rule takes bm = 256 > 128 (one row stripe) and
+# bn = 384, so the unpadded operands end inside the edge blocks
+RAGGED_DW = RAGGED + [(40, 200, 300)]
 RAGGED_SLOW = [(130, 70, 258)]
 
 
@@ -121,14 +126,26 @@ def _check_dw(mkn):
         xq.int8_codes.astype(jnp.float32).T
         @ gq1.int8_codes.astype(jnp.float32), *coeffs)
     a_vec = (aa * bg) * jnp.sum(xq.int8_codes.astype(jnp.float32), axis=0)
-    got_xla = fused_qboth_tn_matmul_xla(x, xq.scale, xq.zero, g, gq1.scale,
-                                        gq1.zero, rbits, a_vec,
-                                        bits_a=8, bits_b=8)
+    # jitted, as the native backend runs it: XLA then fuses the epilogue as
+    # the interpreted kernel's body does (op by op rounds it differently)
+    twin = jax.jit(functools.partial(fused_qboth_tn_matmul_xla, bits_a=8,
+                                     bits_b=8))
+    got_xla = twin(x, xq.scale, xq.zero, g, gq1.scale, gq1.zero, rbits,
+                   a_vec)
     got_pl = fused_qboth_tn_matmul(x, xq.scale, xq.zero, g, gq1.scale,
                                    gq1.zero, rbits, a_vec, bits_a=8,
                                    bits_b=8, interpret=True)
     np.testing.assert_allclose(got_xla, want, rtol=2e-6, atol=2e-4)
     np.testing.assert_allclose(got_pl, want, rtol=2e-6, atol=2e-4)
+    # the shape rule's tiles change only the schedule: exact int32 sums of
+    # the same codes and an elementwise epilogue give the same bits as the
+    # old fixed tiles and as the XLA twin
+    got_fixed = fused_qboth_tn_matmul(x, xq.scale, xq.zero, g, gq1.scale,
+                                      gq1.zero, rbits, a_vec, bits_a=8,
+                                      bits_b=8, bm=128, bn=512, bk=512,
+                                      interpret=True)
+    np.testing.assert_array_equal(np.asarray(got_pl), np.asarray(got_fixed))
+    np.testing.assert_array_equal(np.asarray(got_pl), np.asarray(got_xla))
 
 
 @pytest.mark.parametrize("mkn", RAGGED)
@@ -141,7 +158,7 @@ def test_fused_dx_vs_composed(mkn):
     _check_dx(mkn)
 
 
-@pytest.mark.parametrize("mkn", RAGGED)
+@pytest.mark.parametrize("mkn", RAGGED_DW)
 def test_fused_dw_vs_composed(mkn):
     _check_dw(mkn)
 
@@ -428,6 +445,47 @@ def test_unset_cache_serves_shipped_tiles_and_records_source(monkeypatch):
             at.record_tiles("q8_matmul", shape, (64, 128, 128))
     finally:
         at.reset_cache()
+
+
+# (M, K, N) of every weight-gradient GEMM of the benchmark's cells: Granite
+# q/o, k/v, gate/up, down and the tied head at 8 x 1024 tokens; statquant-tx
+# qkv-sized, fc1, fc2 and the head at 32 x 512 tokens
+DW_SHAPES = [(2048, 8192, 2048), (2048, 8192, 512), (2048, 8192, 8192),
+             (8192, 8192, 2048), (2048, 8192, 49408), (512, 16384, 512),
+             (512, 16384, 1024), (1024, 16384, 512), (512, 16384, 10240)]
+
+
+@pytest.mark.parametrize("mkn", DW_SHAPES)
+def test_fused_dw_rule_tiles(mkn):
+    """The shape rule's tiles are legal, fit the chip's default scoped VMEM,
+    read dY once where M <= 2048, and never stream more HBM bytes than the
+    old fixed (128, 512, 512)."""
+    m, k, n = mkn
+    tiles = at.fused_tn_tiles(m, k, n)
+    assert at.validate_entry("fused_dw", tiles) == []
+    assert at.fused_tn_scoped_vmem_bytes(*tiles) <= at.SCOPED_VMEM_BYTES
+    bm, _, bk = tiles
+    assert bk % 32 == 0
+    if m <= 2048:
+        assert -(-m // bm) == 1, tiles
+    assert at.fused_tn_hbm_bytes(m, k, n, tiles) <= \
+        at.fused_tn_hbm_bytes(m, k, n, at.DEFAULT_TILES)
+
+
+def test_fused_dw_rule_resolves_as_rule_and_cache_overrides(tmp_cache):
+    shape = (2048, 8192, 49408)
+    assert not any(key.startswith("fused_dw/") for key in at.SHIPPED_DEFAULTS)
+    tiles = at.lookup_tiles("fused_dw", shape)
+    assert tiles == at.fused_tn_tiles(*shape)
+    resolved = at.get_cache().resolved
+    assert resolved[("fused_dw", "2048x8192x49408", "int8")] == \
+        (tiles, "rule")
+    at.record_tiles("fused_dw", shape, (128, 512, 256), platform="any")
+    assert at.lookup_tiles("fused_dw", shape) == (128, 512, 256)
+    assert resolved[("fused_dw", "2048x8192x49408", "int8")][1].startswith(
+        "cache:fused_dw/2048x8192x49408/int8/any")
+    # the other kernels keep their shipped or fixed tiles
+    assert at.lookup_tiles("fused_fwd", (7, 7, 7)) == at.DEFAULT_TILES
 
 
 def test_tile_candidates_respect_budget():

@@ -109,6 +109,27 @@ def test_fused_qboth_tn_matmul(one_chip):
              ((TOKENS, FF), F32), ((TOKENS, FF), U32), ((D,), F32))
 
 
+@pytest.mark.parametrize("n", [8192, 49408], ids=["gate", "head"])
+def test_fused_qboth_tn_matmul_granite(one_chip, n):
+    """Granite-3.0-2B's weight gradients at 8 x 1024 tokens: the gate
+    (2048 x 8192) and the tied head (2048 x 49408, not a multiple of the
+    rule's bn).  The shape rule puts all 2048 output rows in one stripe,
+    so dY and its SR bits leave HBM once; the tiles fit the compiler's
+    default scoped VMEM, and no operand is padded nor the output sliced."""
+    from repro.kernels.autotune import fused_tn_tiles
+    from repro.kernels.fused_fqt import fused_qboth_tn_matmul
+    m, k = 2048, 8192
+    assert fused_tn_tiles(m, k, n)[0] == m
+
+    def fn(x, g, rb, a_vec):
+        return fused_qboth_tn_matmul(x, 0.1, -1.0, g, 0.2, -2.0, rb, a_vec,
+                                     bits_a=8, bits_b=5)
+
+    hlo = _compile(fn, one_chip, "fused_qboth_tn_matmul", ((k, m), F32),
+                   ((k, n), F32), ((k, n), U32), ((m,), F32)).as_text()
+    assert not re.search(r"= \S+ (pad|slice)\(", hlo), hlo
+
+
 @pytest.mark.parametrize("mode", ["rows", "tensor"])
 def test_quantize_sr(one_chip, mode):
     from repro.kernels import quantize_sr as q
@@ -254,7 +275,8 @@ def test_granite_step_fits_one_chip(one_chip):
     of the chip's 16, and each GEMM kernel is there at every quantized site
     (the layer body's 7 and the head).  Remat recomputes the body's forward
     GEMMs in the backward scan but for ``down``: its output is only the
-    layer's output, which the backward does not need."""
+    layer's output, which the backward does not need.  The weight-gradient
+    kernel reads the head's 8192 x 49408 dY and SR bits unpadded."""
     import dataclasses
     cfg = dataclasses.replace(get_config("granite-3-2b"), n_layers=5)
     compiled = _compile_step(one_chip, "bhq", cfg=cfg, seq=1024, remat=True,
@@ -262,6 +284,8 @@ def test_granite_step_fits_one_chip(one_chip):
     mem = compiled.memory_analysis()
     used = mem.temp_size_in_bytes + mem.argument_size_in_bytes
     assert used < 15 * 2 ** 30, used / 2 ** 30
-    assert _kernel_calls(compiled.as_text()) == {
+    hlo = compiled.as_text()
+    assert _kernel_calls(hlo) == {
         "fused_qlhs_matmul": 7 + 6 + 1, "fused_qboth_tn_matmul": 7 + 1,
         "q8_matmul": 7 + 1}
+    assert not re.search(r"= [fu]32\[8192,\d+\]\S* pad\(", hlo)
