@@ -39,7 +39,7 @@ comparison — a single-run version of this bench once showed int8-KV slower
 than fp32 at slots=8 for exactly that reason.
 
 Wall-clock numbers are XLA-path only (interpret-mode Pallas timing on CPU is
-meaningless — see BENCH_kernels.json conventions); the pallas parity row
+meaningless); the pallas parity row
 runs the fused dequant kernel in interpret mode for *numerics*, not speed.
 """
 

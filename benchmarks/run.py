@@ -6,9 +6,6 @@ Prints ``name,us_per_call,derived`` CSV.  Suites:
   fig4_*     quantization bin-size distributions       (paper Fig. 4)
   table1_*   convergence vs (quantizer x bits)         (paper Table 1 proxy)
   wag_*      ultra-low-bit (W, A, G) sweep w/ theory overlay (DoReFa-style)
-  overhead_* quantization overhead vs GEMM             (paper Sec. 4.3)
-  kernel_*   kernel timings + TPU-target properties
-  train_*    engine step throughput (donation x accumulation)
   serve_*    continuous-batching serving (fp32 vs int8 KV cache)
 
 Select suites with ``python -m benchmarks.run fig3 table1 ...`` (default all).
@@ -21,18 +18,13 @@ import traceback
 
 
 def main() -> None:
-    from . import (bench_bins, bench_convergence, bench_kernels,
-                   bench_overhead, bench_serve, bench_train_step,
-                   bench_variance)
+    from . import bench_bins, bench_convergence, bench_serve, bench_variance
 
     suites = {
         "fig3": bench_variance.run,
         "fig4": bench_bins.run,
         "table1": bench_convergence.run,
         "wag": bench_convergence.wag_matrix,
-        "overhead": bench_overhead.run,
-        "kernel": bench_kernels.run,
-        "train": bench_train_step.run,
         "serve": bench_serve.run,
     }
     selected = sys.argv[1:] or list(suites)

@@ -182,7 +182,7 @@ def main(argv=None) -> int:
     sys.path.insert(0, src)
     import jax
     from repro.configs import get_config
-    from repro.kernels.autotune import get_cache
+    from repro.kernels.tiling import RESOLVED
     from repro.launch.device import device_summary, enable_compile_cache
 
     cache_dir = enable_compile_cache()
@@ -205,9 +205,9 @@ def main(argv=None) -> int:
     else:
         train_phase(cfg, args.seed)
         serve_phase(cfg, args.seed)
-    for (kernel, shape, dtype), (tiles, source) in sorted(
-            get_cache().resolved.items()):
-        print(f"[tiles] {kernel} {shape} {dtype}: {tiles} from {source}")
+    for (kernel, shape), (tiles, source) in sorted(RESOLVED.items()):
+        print(f"[tiles] {kernel} {'x'.join(map(str, shape))}: {tiles} "
+              f"from {source}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}), flush=True)

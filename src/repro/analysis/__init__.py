@@ -27,13 +27,11 @@ loop without touching a device:
   ``ranges``     (:mod:`.ranges`)  int32-accumulator overflow bounds for
                  intM x intN GEMMs (asymmetric widths), scale-degeneracy
                  checks.
-  ``kernels``    (:mod:`.kernels`) static validation of every Pallas tile
-                 choice (shipped + persisted tuning cache).
   ``tracing``    (:mod:`.tracing`) retrace counter + donation verifier for
                  the jitted engine step.
   ``lint``       (:mod:`.lint`)    AST rules RPR001-003 over layers/models.
 
-CLI: ``python -m repro.analysis {audit|soundness|plan|lint|kernels}``
+CLI: ``python -m repro.analysis {audit|soundness|plan|lint}``
 (see __main__.py); every subcommand accepts ``--format json``; exits
 non-zero on any violation, so CI gates on it.
 """
@@ -41,7 +39,6 @@ non-zero on any violation, so CI gates on it.
 from .audit import (AuditReport, SelftestResult, Violation, audit_fn,
                     audit_model, audit_step, mutation_selftest)
 from .graph import GemmSite, classify_stack, iter_gemm_sites, site_flops
-from .kernels import KernelCheckReport, KernelFinding, check_kernels
 from .lint import LintFinding, lint_file, lint_source, lint_tree
 from .planner import (Candidate, Plan, PlanEntry, PlanSite,
                       collect_plan_sites, gemm_bytes_moved, legal_widths,
@@ -65,7 +62,6 @@ __all__ = [
     "legal_widths",
     "RangeFinding", "check_sites", "accumulator_bound", "max_safe_k",
     "headroom_bits", "signed_code_bound",
-    "KernelCheckReport", "KernelFinding", "check_kernels",
     "LintFinding", "lint_source", "lint_file", "lint_tree",
     "RetraceGuard", "DonationReport", "check_donation",
     "check_step_donation",

@@ -1,4 +1,4 @@
-"""CLI: ``python -m repro.analysis {audit|soundness|plan|lint|kernels}``.
+"""CLI: ``python -m repro.analysis {audit|soundness|plan|lint}``.
 
 Exit status is the contract: 0 = clean, 1 = violations — CI gates on it
 (.github/workflows/ci.yml ``analysis`` job).  Everything runs on CPU at
@@ -23,8 +23,6 @@ subcommand accepts ``--format json`` for machine-readable findings
              (greedy + exact DP); writes QuantPolicy.overrides JSON for
              ``launch/train.py --override-file``.
   lint       AST rules RPR001-003 over src/repro/{layers,models}.
-  kernels    static tile validation (shipped defaults + persisted tuning
-             cache); ``--purge`` removes bad/stale persisted entries.
 """
 
 from __future__ import annotations
@@ -167,26 +165,6 @@ def _cmd_lint(ns) -> int:
     return 1 if findings else 0
 
 
-def _cmd_kernels(ns) -> int:
-    from .kernels import check_kernels, purge_bad_entries
-
-    report = check_kernels(ns.cache)
-    text = report.format(verbose=ns.verbose)
-    purged = None
-    if ns.purge:
-        purged = purge_bad_entries(report)
-        text += (f"\npurged {purged} bad/stale cache "
-                 f"entr{'y' if purged == 1 else 'ies'}")
-    doc = {"tool": "kernels", "ok": report.ok,
-           "findings": [{"rule": f"kernel/{f.severity}",
-                         "severity": f.severity, "path": str(f.key),
-                         "source": f.source, "detail": f.detail}
-                        for f in report.findings],
-           **({"purged": purged} if purged is not None else {})}
-    _emit(ns, doc, text)
-    return 0 if report.ok else 1
-
-
 def _add_common(p, step_help: str):
     p.add_argument("--config", action="append",
                    help="arch config name (repeatable; default: the two "
@@ -208,8 +186,7 @@ def main(argv=None) -> int:
         prog="python -m repro.analysis",
         description="Static analysis of the quantization contract: "
                     "contract audit, statistical-soundness verifier, "
-                    "variance-budget precision planner, repo lint, kernel "
-                    "tile validation.")
+                    "variance-budget precision planner, repo lint.")
     sub = parser.add_subparsers(dest="cmd", required=True)
 
     p = sub.add_parser("audit", help="jaxpr quantization-contract audit")
@@ -256,16 +233,6 @@ def main(argv=None) -> int:
                         "src/repro/layers + src/repro/models)")
     p.add_argument("--format", default="text", choices=["text", "json"])
     p.set_defaults(fn=_cmd_lint)
-
-    p = sub.add_parser("kernels", help="static Pallas tile validation")
-    p.add_argument("--cache", default=None,
-                   help="tuning-cache path (default: $REPRO_TUNING_CACHE; "
-                        "unset = shipped tiles only)")
-    p.add_argument("--purge", action="store_true",
-                   help="remove bad/stale persisted entries")
-    p.add_argument("--format", default="text", choices=["text", "json"])
-    p.add_argument("-v", "--verbose", action="store_true")
-    p.set_defaults(fn=_cmd_kernels)
 
     ns = parser.parse_args(argv)
     return ns.fn(ns)

@@ -15,8 +15,8 @@ Per quantized gradient GEMM site this module combines
   * the exact conditional variance of each candidate quantizer/width from
     :func:`repro.core.theory.quantizer_variance` (Proposition 4 closed
     forms), evaluated on a fixed-seed Gaussian proxy of the SR operand,
-  * a bytes-moved cost model (:func:`gemm_bytes_moved`) shared with
-    ``benchmarks/bench_kernels.py``'s bytes column,
+  * a bytes-moved cost model (:func:`gemm_bytes_moved`: operands in at
+    their code width, f32 result out),
 
 into per-site (variance, bytes) candidates at each legal width {8, 4, 2},
 prunes the Pareto-dominated ones, and solves
@@ -68,9 +68,8 @@ _SAMPLE_CAP = 1 << 16
 def gemm_bytes_moved(m: int, k: int, n: int, lhs_bits: int,
                      rhs_bits: int, out_bytes: int = 4) -> float:
     """HBM bytes one (m, k) x (k, n) GEMM moves: packed sub-byte operands
-    in, fp32 (by default) result out.  This is the same model behind the
-    ``bytes_moved`` column in ``benchmarks/bench_kernels.py`` (f32 = 32/32,
-    int8 = 8/8, packed W4/W2/W1 = 8/wbits)."""
+    in, fp32 (by default) result out (f32 = 32/32, int8 = 8/8, packed
+    W4/W2/W1 = 8/wbits)."""
     return m * k * lhs_bits / 8.0 + k * n * rhs_bits / 8.0 + out_bytes * m * n
 
 

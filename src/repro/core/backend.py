@@ -426,7 +426,7 @@ def fused_fqt_dw(x2: jax.Array, scale_x, zero_x, bits_act: int,
     if backend == "pallas":
         return fused_qboth_tn_matmul(
             x2, scale_x, zero_x, g2, sg, zg, rbits, a_vec,
-            bits_a=bits_act, bits_b=bits_wgrad, tune_key="fused_dw",
+            bits_a=bits_act, bits_b=bits_wgrad,
             interpret=resolve_interpret(interpret))
     if backend == "native":
         return fused_qboth_tn_matmul_xla(x2, scale_x, zero_x, g2, sg, zg,

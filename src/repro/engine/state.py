@@ -5,7 +5,8 @@ pytree is what makes the production step shape possible:
 
   * ``jax.jit(..., donate_argnums=(0,))`` donates the *whole* state — params
     and optimizer moments are updated in place, halving peak HBM for the
-    update (measured by ``benchmarks/bench_train_step.py``);
+    update (``analysis.tracing.check_step_donation`` checks that the
+    donated buffers die);
   * ``state_shardings(plan, abstract)`` derives one sharding tree for the
     state from the :class:`~repro.sharding.ShardingPlan` param rules, so
     ``in_shardings == out_shardings`` and jit never inserts resharding

@@ -7,7 +7,7 @@
   quantize_sr.py   fused dynamic-range + scale + stochastic-round quantize
   kv_dequant.py    fused affine dequantize of int8 KV-cache rows
   kv_gather.py     block-table page gather + dequantize (paged serving)
-  autotune.py      tile-shape autotuner + persisted per-shape cache
+  tiling.py        tiles (shape rule or default) + pad-and-slice helpers
   ops.py           wrappers wiring kernels to the quantizer algebra
   ref.py           pure-jnp oracles (the allclose targets)
 
@@ -16,8 +16,6 @@ NOTE: ``ops`` is intentionally NOT imported here — it depends on
 import would cycle.  Use ``from repro.kernels.ops import ...``.
 """
 
-from .autotune import (autotune, lookup_tiles, q8_tile_vmem_bytes,
-                       record_tiles, tile_candidates)
 from .fused_fqt import (fused_qboth_tn_matmul, fused_qboth_tn_matmul_xla,
                         fused_qlhs_matmul, fused_qlhs_matmul_xla,
                         fused_qlhs_packed_matmul,
@@ -29,14 +27,14 @@ from .pack import (PackedTensor, codes_per_byte, max_safe_k_packed,
 from .q4_matmul import packed_matmul, packed_matmul_xla
 from .q8_matmul import q8_matmul
 from .quantize_sr import quantize_sr_rows, quantize_sr_tensor
+from .tiling import lookup_tiles
 
 __all__ = [
     "q8_matmul", "quantize_sr_rows", "quantize_sr_tensor", "kv_dequant_rows",
     "kv_gather_pages", "kv_gather_pages_xla",
     "fused_qlhs_matmul", "fused_qlhs_matmul_xla", "fused_qboth_tn_matmul",
     "fused_qboth_tn_matmul_xla", "fused_qlhs_packed_matmul",
-    "fused_qlhs_packed_matmul_xla", "autotune", "lookup_tiles",
-    "record_tiles", "tile_candidates", "q8_tile_vmem_bytes",
+    "fused_qlhs_packed_matmul_xla", "lookup_tiles",
     "PackedTensor", "codes_per_byte", "pack_codes", "unpack_codes",
     "pack_qtensor", "packed_nbytes", "max_safe_k_packed",
     "packed_matmul", "packed_matmul_xla",

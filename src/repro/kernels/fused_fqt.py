@@ -43,9 +43,10 @@ partial sums of 2^24 (codes are <= 2^8, products <= 2^14, so exact for
 K <= 2^10 and within ~2^-24 relative beyond — noise next to quantization
 error).
 
-Tile shapes come from the persisted autotuner cache
-(``kernels/autotune.py``) unless given explicitly; bad explicit tiles fail
-fast in ``check_tiles`` with the shape and tile in the message.
+Tile shapes come from ``tiling.lookup_tiles`` (the weight-gradient
+kernel's shape rule, else ``DEFAULT_TILES``) unless given explicitly; bad
+explicit tiles fail fast in ``check_tiles`` with the shape and tile in the
+message.
 
 Padding: the LHS kernels zero-pad float operands and epilogue vectors to
 tile multiples and slice the output back.  The weight-gradient kernel pads
@@ -67,9 +68,8 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from . import names
-from .autotune import lookup_tiles
 from .pack import codes_per_byte, max_safe_k_packed, unpack_tile
-from .tiling import (check_bits, check_tiles, pad2d as _pad2,
+from .tiling import (check_bits, check_tiles, lookup_tiles, pad2d as _pad2,
                      pad_rows as _pad_rows, round_up as _round_up,
                      unit_from_bits)
 
@@ -140,7 +140,7 @@ def fused_qlhs_matmul(xf: jax.Array, scale_a: jax.Array, zero_a: jax.Array,
     (K, N) or — ``trans_b=True`` — (N, K); alpha_b/beta_b: scalar affine
     factors of the RHS; u_vec: (N,) precomputed RHS epilogue column vector
     ``alpha_b * colsum(y8) + K * beta_b`` (colsum over the contraction).
-    Returns (M, N) f32.  Tiles default to the autotuner cache under
+    Returns (M, N) f32.  Tiles default to ``tiling.lookup_tiles`` under
     ``tune_key``.
     """
     check_bits("fused_qlhs_matmul", bits)
@@ -251,8 +251,8 @@ def fused_qboth_tn_matmul(af: jax.Array, scale_a, zero_a, bf: jax.Array,
                           scale_b, zero_b, rbits: jax.Array,
                           a_vec: jax.Array, *, bits_a: int, bits_b: int,
                           bm: Optional[int] = None, bn: Optional[int] = None,
-                          bk: Optional[int] = None, interpret: bool = False,
-                          tune_key: str = "fused_dw") -> jax.Array:
+                          bk: Optional[int] = None,
+                          interpret: bool = False) -> jax.Array:
     """``Q_det(af).T @ Q_sr(bf)`` with both quantizes fused into the K-sweep.
 
     af: (K, M) f32 storage (the GEMM contracts over the K storage rows);
@@ -270,7 +270,7 @@ def fused_qboth_tn_matmul(af: jax.Array, scale_a, zero_a, bf: jax.Array,
         raise ValueError(
             f"fused_qboth_tn_matmul: contraction mismatch — af {af.shape} "
             f"vs bf {bf.shape} (both contract over storage rows)")
-    tm, tn, tk = lookup_tiles(tune_key, (M, K, N))
+    tm, tn, tk = lookup_tiles("fused_dw", (M, K, N))
     bm, bn, bk = (tm if bm is None else bm, tn if bn is None else bn,
                   tk if bk is None else bk)
     # A tile is (bk, bm): bm lands on the lane dim (128), bk on the f32
@@ -366,8 +366,7 @@ def fused_qlhs_packed_matmul(xf: jax.Array, scale_a: jax.Array,
                              bm: Optional[int] = None,
                              bn: Optional[int] = None,
                              bk: Optional[int] = None,
-                             interpret: bool = False,
-                             tune_key: str = "fused_packed") -> jax.Array:
+                             interpret: bool = False) -> jax.Array:
     """``Q_det(xf) @ W-hat`` with W bit-packed in HBM: the forward megakernel
     of the ultra-low-bit track.  Quantizes the (bm, bk) activation tile AND
     unpacks the (bk/ppb, bn) weight tile in VMEM inside the K-sweep, so no
@@ -395,7 +394,7 @@ def fused_qlhs_packed_matmul(xf: jax.Array, scale_a: jax.Array,
             f"fused_qlhs_packed_matmul: K={K} overflows the int32 "
             f"accumulator for int{bits} x int{wbits} codes "
             f"(max_safe_k={safe})")
-    tm, tn, tk = lookup_tiles(tune_key, (M, K, N), dtype=f"int{wbits}")
+    tm, tn, tk = lookup_tiles("fused_packed", (M, K, N))
     bm, bn, bk = (tm if bm is None else bm, tn if bn is None else bn,
                   tk if bk is None else bk)
     bm = min(bm, _round_up(M, 8))        # f32 A tile: sublane 8

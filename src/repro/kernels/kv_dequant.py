@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import names
-from .autotune import lookup_tiles
 from .tiling import (check_bits, pad2d as _pad2, pad_rows as _pad_rows,
                      round_up as _round_up)
 
@@ -35,7 +34,7 @@ def _kernel(codes_ref, scale_ref, zero_ref, out_ref, *, off: int):
 
 
 def kv_dequant_rows(codes8: jax.Array, scale: jax.Array, zero: jax.Array,
-                    bits: int = 8, bm: int = None,
+                    bits: int = 8, bm: int = 256,
                     interpret: bool = False) -> jax.Array:
     """Dequantize per-row affine int8 codes. codes8: (M, N) int8 shifted by
     ``-2^(b-1)``; scale/zero: (M, 1) f32.  Returns (M, N) f32.
@@ -43,11 +42,8 @@ def kv_dequant_rows(codes8: jax.Array, scale: jax.Array, zero: jax.Array,
     Arbitrary (M, N) works: rows are edge-padded to a block multiple (edge
     padding keeps the padded scales finite), columns zero-padded to a lane
     multiple (dequantized garbage is sliced off), output sliced back.
-    ``bm`` defaults to the autotuner cache's shape-agnostic ``rows`` entry.
     """
     check_bits("kv_dequant_rows", bits)
-    if bm is None:
-        bm = lookup_tiles("kv_dequant", ("rows",), default=(256, 0, 0))[0]
     return _kv_dequant_rows(codes8, scale, zero, bits=bits, bm=bm,
                             interpret=interpret)
 

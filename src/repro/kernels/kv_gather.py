@@ -33,7 +33,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import names
-from .autotune import lookup_tiles
 from .tiling import check_bits, round_up as _round_up
 
 __all__ = ["kv_gather_pages", "kv_gather_pages_xla"]
@@ -58,7 +57,7 @@ def _row_block(P: int, bm: int) -> int:
 
 
 def kv_gather_pages(codes: jax.Array, scale: jax.Array, zero: jax.Array,
-                    table: jax.Array, bits: int = 8, bm: int = None,
+                    table: jax.Array, bits: int = 8, bm: int = 256,
                     interpret: bool = False) -> jax.Array:
     """Gather + dequantize paged int8 KV rows through a block table.
 
@@ -67,14 +66,12 @@ def kv_gather_pages(codes: jax.Array, scale: jax.Array, zero: jax.Array,
     order).  Returns (B, nb*P, D) f32 — each request's cache, contiguous
     again.
 
-    ``bm`` (rows per grid step, autotuner key ``kv_gather/rows``) is clamped
-    to a divisor of the page size; on real TPUs page sizes should be
-    multiples of 8 so the f32 sublane tiling holds.  Column dim is
+    ``bm`` (rows per grid step) is clamped to a divisor of the page size;
+    on real TPUs page sizes should be multiples of 8 so the f32 sublane
+    tiling holds.  Column dim is
     zero-padded to the 128 lane width and sliced back.
     """
     check_bits("kv_gather_pages", bits)
-    if bm is None:
-        bm = lookup_tiles("kv_gather", ("rows",), default=(256, 0, 0))[0]
     return _kv_gather_pages(codes, scale, zero, table, bits=bits, bm=bm,
                             interpret=interpret)
 

@@ -42,10 +42,9 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from . import names
-from .autotune import lookup_tiles
 from .fused_fqt import _codes_dot
 from .pack import codes_per_byte, max_safe_k_packed, unpack_tile
-from .tiling import (check_bits, check_tiles, pad2d as _pad2,
+from .tiling import (check_bits, check_tiles, lookup_tiles, pad2d as _pad2,
                      round_up as _round_up)
 
 __all__ = ["packed_matmul", "packed_matmul_xla"]
@@ -105,15 +104,14 @@ def packed_matmul(x8: jax.Array, packed: jax.Array, rs: jax.Array,
     packed (ceil(K/ppb), N) uint8 at ``wbits`` codes/byte; rs/r2/a: (M,);
     cs/u/b: (N,) — the standard epilogue coefficient vectors of
     ``core/backend.epilogue_coeffs`` (u's colsum runs over the *unpacked*
-    codes).  Returns (M, N) f32.  Tiles default to the autotuner cache under
-    ``q4_matmul`` keyed by the logical (M, K, N) and an ``int{wbits}``
-    dtype tag.
+    codes).  Returns (M, N) f32.  Tiles default to
+    ``tiling.lookup_tiles("q4_matmul", ...)`` at the logical (M, K, N).
     """
     ppb = _check_packed_gemm("packed_matmul", x8, packed, wbits, kdim)
     del ppb
     M, K = x8.shape
     N = packed.shape[1]
-    tm, tn, tk = lookup_tiles("q4_matmul", (M, K, N), dtype=f"int{wbits}")
+    tm, tn, tk = lookup_tiles("q4_matmul", (M, K, N))
     bm, bn, bk = (tm if bm is None else bm, tn if bn is None else bn,
                   tk if bk is None else bk)
     bm = min(bm, _round_up(M, 32))       # int8 sublane tile is 32

@@ -30,8 +30,8 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from . import names
-from .autotune import lookup_tiles
-from .tiling import (check_tiles, pad2d as _pad2, round_up as _round_up)
+from .tiling import (check_tiles, lookup_tiles, pad2d as _pad2,
+                     round_up as _round_up)
 
 __all__ = ["q8_matmul"]
 
@@ -60,8 +60,8 @@ def q8_matmul(x8: jax.Array, y8: jax.Array, rs: jax.Array, cs: jax.Array,
               interpret: bool = False) -> jax.Array:
     """x8: (M,K) int8; y8: (K,N) int8; rs/r2/a: (M,); cs/u/b: (N,) -> f32.
 
-    Tiles default to the persisted autotuner cache for this (M, K, N)
-    (``kernels/autotune.py``; explicit bm/bn/bk override it), shrink toward
+    Tiles default to ``tiling.lookup_tiles("q8_matmul", (M, K, N))``
+    (explicit bm/bn/bk override it), shrink toward
     small dims (keeping MXU-friendly multiples), then every dim is
     zero-padded up to a tile multiple and the result sliced back.
     Zero-padding is exact — padded K codes contribute 0 to the accumulator

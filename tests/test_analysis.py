@@ -1,4 +1,4 @@
-"""repro.analysis: contract auditor, range analysis, kernel checker, lint.
+"""repro.analysis: contract auditor, range analysis, lint.
 
 The auditor's own correctness is established adversarially: the mutation
 self-test plants a raw ``jnp.dot`` in an MLP and the audit must turn red
@@ -9,7 +9,6 @@ it).
 """
 
 import functools
-import importlib
 import json
 
 import jax
@@ -18,15 +17,13 @@ import numpy as np
 import pytest
 
 from repro.analysis import (RetraceGuard, audit_fn, audit_model, audit_step,
-                            check_donation, check_kernels, lint_source,
-                            lint_tree, mutation_selftest)
-from repro.analysis.kernels import purge_bad_entries
+                            check_donation, lint_source, lint_tree,
+                            mutation_selftest)
 from repro.analysis.ranges import (accumulator_bound, check_scale_inputs,
                                    headroom_bits, max_safe_k,
                                    signed_code_bound)
 from repro.configs import get_config
 from repro.core import QuantPolicy, fp_exempt, quant_scope
-at = importlib.import_module("repro.kernels.autotune")
 
 FQT8 = QuantPolicy.fqt("bhq", 8)
 
@@ -285,81 +282,6 @@ def test_range_check_rides_the_audit():
 
 
 # ---------------------------------------------------------------------------
-# Kernel tile checker + hardened cache loading
-# ---------------------------------------------------------------------------
-
-@pytest.fixture
-def tmp_cache(tmp_path, monkeypatch):
-    path = tmp_path / "tuning.json"
-    monkeypatch.setenv(at.ENV_CACHE, str(path))
-    at.reset_cache()
-    yield path
-    at.reset_cache()
-
-
-_BAD_CACHE = {
-    # legal: aligned, under budget
-    "q8_matmul/64x128x128/int8/any": {"bm": 64, "bn": 128, "bk": 128},
-    # illegal: bm not a multiple of 32 for the int8 A tile
-    "q8_matmul/64x128x128/int8/cpu": {"bm": 48, "bn": 128, "bk": 128},
-    # illegal: blows the 12 MiB VMEM budget (fused_tn accounting)
-    "fused_dw/1024x512x1024/int8/any": {"bm": 512, "bn": 1024, "bk": 1024},
-    # malformed entry shape
-    "fused_fwd/512x1024x1024/int8/any": [128, 512, 512],
-    # unknown kernel: kept by the loader, flagged stale by the checker
-    "mystery_kernel/8x8x8/int8/any": {"bm": 8, "bn": 8, "bk": 8},
-}
-
-
-def test_loader_drops_illegal_entries_with_warning(tmp_cache):
-    tmp_cache.write_text(json.dumps(_BAD_CACHE))
-    at.reset_cache()
-    with pytest.warns(UserWarning, match="dropped 3 illegal entries"):
-        tiles = at.lookup_tiles("q8_matmul", (64, 128, 128))
-    assert tiles == (64, 128, 128)           # the legal "any" entry survives
-    # the illegal platform-specific entry was dropped, not served
-    cache = at.get_cache()
-    assert cache.lookup("q8_matmul/64x128x128/int8/cpu") is None
-    assert cache.lookup("fused_dw/1024x512x1024/int8/any") is None
-    # unknown-kernel entry is kept (forward compat)
-    assert cache.lookup("mystery_kernel/8x8x8/int8/any") == (8, 8, 8)
-
-
-def test_validate_entry():
-    assert at.validate_entry("q8_matmul", (64, 128, 128)) == []
-    assert at.validate_entry("nope", (64, 128, 128)) is None
-    assert at.validate_entry("q8_matmul", (48, 128, 128))      # misaligned
-    assert at.validate_entry("fused_dw", (512, 1024, 1024))    # over budget
-    assert at.validate_entry("kv_dequant", (256, 0, 0)) == []
-    assert at.validate_entry("kv_dequant", (256, 128, 0))      # bn must be 0
-
-
-def test_kernel_checker_and_purge(tmp_cache):
-    tmp_cache.write_text(json.dumps(dict(
-        _BAD_CACHE, **{"q8_matmul/8x8": {"bm": 32, "bn": 128, "bk": 128}})))
-    report = check_kernels(str(tmp_cache))
-    assert not report.ok
-    bad = {f.key for f in report.findings if f.severity == "error"}
-    assert "q8_matmul/64x128x128/int8/cpu" in bad          # misaligned
-    assert "fused_dw/1024x512x1024/int8/any" in bad        # over budget
-    assert "fused_fwd/512x1024x1024/int8/any" in bad       # malformed
-    assert "q8_matmul/8x8" in bad                          # bad key shape
-    stale = {f.key for f in report.findings if f.severity == "stale"}
-    assert "mystery_kernel/8x8x8/int8/any" in stale
-
-    n = purge_bad_entries(report)
-    assert n == 5
-    clean = check_kernels(str(tmp_cache))
-    assert clean.ok and clean.n_cache == 1                 # only the good one
-
-
-def test_shipped_defaults_are_legal():
-    report = check_kernels("/nonexistent/tuning.json")
-    assert report.ok, report.format()
-    assert report.n_shipped == len(at.SHIPPED_DEFAULTS)
-
-
-# ---------------------------------------------------------------------------
 # Retrace + donation guards
 # ---------------------------------------------------------------------------
 
@@ -497,12 +419,3 @@ def test_cli_lint_json(capsys):
     doc = json.loads(capsys.readouterr().out)
     assert doc["tool"] == "lint" and doc["ok"] == (rc == 0)
     assert isinstance(doc["findings"], list)
-
-
-def test_cli_kernels_json(tmp_cache, capsys):
-    from repro.analysis.__main__ import main
-    rc = main(["kernels", "--format", "json"])
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["tool"] == "kernels" and doc["ok"] == (rc == 0)
-    for f in doc["findings"]:
-        assert {"rule", "severity", "path", "detail"} <= set(f)

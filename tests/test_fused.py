@@ -1,4 +1,4 @@
-"""Fused quantize->GEMM->epilogue megakernels + the tiling autotuner.
+"""Fused quantize->GEMM->epilogue megakernels + the tiles they run at.
 
 Three layers of evidence:
 
@@ -12,13 +12,14 @@ Three layers of evidence:
     *tight* fused-vs-unfused check on the native backend (same codes, same
     f32 accumulation — this is the bit-identical-SR evidence: a single
     differing uniform would shift a code by a full bin).
-  * autotuner — sweep/persist/lookup plumbing with a fake timer and a
-    tmpdir cache, including corrupt-cache fallback and lookup precedence.
+  * tiles — the weight-gradient kernel's shape rule, rule-vs-default
+    resolution, and the tiles at every kernel site of the benchmark's
+    training cells, pinned so that a change to them is a deliberate one.
 """
 
 import functools
-import importlib
-import json
+import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +31,7 @@ from repro.core.backend import (affine_factors, apply_epilogue,
                                 epilogue_coeffs, requantize_det)
 from repro.core.quantizers import (quantize_psq_stoch, quantize_ptq_det,
                                    quantize_ptq_stoch)
-# the package re-exports the autotune *function*; import the module itself
-at = importlib.import_module("repro.kernels.autotune")
+from repro.kernels import tiling
 from repro.kernels.fused_fqt import (fused_qboth_tn_matmul,
                                      fused_qboth_tn_matmul_xla,
                                      fused_qlhs_matmul, fused_qlhs_matmul_xla)
@@ -349,103 +349,8 @@ def test_quantize_sr_rows_ragged_positive_rows():
 
 
 # ---------------------------------------------------------------------------
-# Autotuner
+# Tiles
 # ---------------------------------------------------------------------------
-
-@pytest.fixture
-def tmp_cache(tmp_path, monkeypatch):
-    path = tmp_path / "tuning.json"
-    monkeypatch.setenv(at.ENV_CACHE, str(path))
-    at.reset_cache()
-    yield path
-    at.reset_cache()
-
-
-def test_autotune_picks_fastest_and_persists(tmp_cache):
-    calls = []
-
-    def fake_timer(tiles):
-        calls.append(tiles)
-        return {(32, 128, 128): 50.0, (64, 128, 128): 10.0,
-                (128, 128, 128): 99.0}[tiles]
-
-    best = at.autotune("q8_matmul", (64, 128, 128), fake_timer,
-                       candidates=[(32, 128, 128), (64, 128, 128),
-                                   (128, 128, 128)])
-    assert best == (64, 128, 128)
-    assert len(calls) == 3
-    assert tmp_cache.exists()
-    # a fresh cache object reads the persisted winner back
-    at.reset_cache()
-    assert at.lookup_tiles("q8_matmul", (64, 128, 128)) == (64, 128, 128)
-    data = json.loads(tmp_cache.read_text())
-    [key] = data
-    assert key.startswith("q8_matmul/64x128x128/int8/")
-    assert data[key]["us_per_call"] == 10.0
-
-
-def test_autotune_skips_raising_candidates(tmp_cache):
-    def flaky(tiles):
-        if tiles[0] == 32:
-            raise RuntimeError("bad tile")
-        return 1.0
-
-    best = at.autotune("q8_matmul", (64, 128, 128), flaky,
-                       candidates=[(32, 128, 128), (64, 128, 128)])
-    assert best == (64, 128, 128)
-    with pytest.raises(ValueError, match="every candidate failed"):
-        at.autotune("q8_matmul", (64, 128, 128),
-                    lambda t: (_ for _ in ()).throw(RuntimeError("x")),
-                    candidates=[(32, 128, 128)])
-
-
-def test_corrupt_cache_falls_back(tmp_cache):
-    tmp_cache.write_text("{not json")
-    at.reset_cache()
-    with pytest.warns(UserWarning, match="corrupt tuning cache"):
-        tiles = at.lookup_tiles("q8_matmul", (512, 1024, 1024))
-    # shipped default still reachable through the degraded cache
-    assert tiles == at.SHIPPED_DEFAULTS["q8_matmul/512x1024x1024"]
-
-
-def test_lookup_precedence(tmp_cache):
-    shape = (512, 1024, 1024)
-    # shipped default applies with an empty cache
-    assert at.lookup_tiles("q8_matmul", shape) == \
-        at.SHIPPED_DEFAULTS["q8_matmul/512x1024x1024"]
-    # platform-agnostic "any" beats shipped
-    at.record_tiles("q8_matmul", shape, (64, 128, 128), platform="any")
-    assert at.lookup_tiles("q8_matmul", shape) == (64, 128, 128)
-    # platform-specific beats "any"
-    at.record_tiles("q8_matmul", shape, (32, 256, 128),
-                    platform=jax.default_backend())
-    assert at.lookup_tiles("q8_matmul", shape) == (32, 256, 128)
-    # unknown shape/kernel falls through to the caller's default
-    assert at.lookup_tiles("q8_matmul", (7, 7, 7), default=(1, 2, 3)) == \
-        (1, 2, 3)
-
-
-def test_unset_cache_serves_shipped_tiles_and_records_source(monkeypatch):
-    """Without $REPRO_TUNING_CACHE no file is read or written: tiles come
-    from what the repository ships, and each lookup records its source."""
-    monkeypatch.delenv(at.ENV_CACHE, raising=False)
-    at.reset_cache()
-    try:
-        assert at.cache_path() is None
-        shape = (512, 1024, 1024)
-        shipped = at.SHIPPED_DEFAULTS["q8_matmul/512x1024x1024"]
-        assert at.lookup_tiles("q8_matmul", shape) == shipped
-        assert at.lookup_tiles("fused_fwd", (7, 7, 7)) == at.DEFAULT_TILES
-        resolved = at.get_cache().resolved
-        assert resolved[("q8_matmul", "512x1024x1024", "int8")] == \
-            (shipped, "shipped")
-        assert resolved[("fused_fwd", "7x7x7", "int8")] == \
-            (at.DEFAULT_TILES, "default")
-        with pytest.raises(ValueError, match=at.ENV_CACHE):
-            at.record_tiles("q8_matmul", shape, (64, 128, 128))
-    finally:
-        at.reset_cache()
-
 
 # (M, K, N) of every weight-gradient GEMM of the benchmark's cells: Granite
 # q/o, k/v, gate/up, down and the tied head at 8 x 1024 tokens; statquant-tx
@@ -457,69 +362,112 @@ DW_SHAPES = [(2048, 8192, 2048), (2048, 8192, 512), (2048, 8192, 8192),
 
 @pytest.mark.parametrize("mkn", DW_SHAPES)
 def test_fused_dw_rule_tiles(mkn):
-    """The shape rule's tiles are legal, fit the chip's default scoped VMEM,
-    read dY once where M <= 2048, and never stream more HBM bytes than the
-    old fixed (128, 512, 512)."""
+    """The shape rule's tiles are aligned as the kernel requires (bm and bn
+    on 128 lanes, bk on 8 sublanes), fit both VMEM budgets, read dY once
+    where M <= 2048, and never stream more HBM bytes than the old fixed
+    (128, 512, 512)."""
     m, k, n = mkn
-    tiles = at.fused_tn_tiles(m, k, n)
-    assert at.validate_entry("fused_dw", tiles) == []
-    assert at.fused_tn_scoped_vmem_bytes(*tiles) <= at.SCOPED_VMEM_BYTES
-    bm, _, bk = tiles
+    tiles = tiling.fused_tn_tiles(m, k, n)
+    bm, bn, bk = tiles
+    assert bm > 0 and bn > 0 and bk > 0, tiles
+    assert bm % 128 == 0 and bn % 128 == 0 and bk % 8 == 0, tiles
+    assert tiling.fused_tn_vmem_bytes(*tiles) <= tiling.VMEM_BUDGET_BYTES
+    assert tiling.fused_tn_scoped_vmem_bytes(*tiles) <= \
+        tiling.SCOPED_VMEM_BYTES
     assert bk % 32 == 0
     if m <= 2048:
         assert -(-m // bm) == 1, tiles
-    assert at.fused_tn_hbm_bytes(m, k, n, tiles) <= \
-        at.fused_tn_hbm_bytes(m, k, n, at.DEFAULT_TILES)
+    assert tiling.fused_tn_hbm_bytes(m, k, n, tiles) <= \
+        tiling.fused_tn_hbm_bytes(m, k, n, tiling.DEFAULT_TILES)
 
 
-def test_fused_dw_rule_resolves_as_rule_and_cache_overrides(tmp_cache):
+def test_fused_dw_rule_resolves_as_rule_and_cache_overrides():
+    """``fused_dw`` resolves through its shape rule; a kernel without a
+    rule gets the default, or the caller's; each lookup is recorded with
+    its source."""
     shape = (2048, 8192, 49408)
-    assert not any(key.startswith("fused_dw/") for key in at.SHIPPED_DEFAULTS)
-    tiles = at.lookup_tiles("fused_dw", shape)
-    assert tiles == at.fused_tn_tiles(*shape)
-    resolved = at.get_cache().resolved
-    assert resolved[("fused_dw", "2048x8192x49408", "int8")] == \
-        (tiles, "rule")
-    at.record_tiles("fused_dw", shape, (128, 512, 256), platform="any")
-    assert at.lookup_tiles("fused_dw", shape) == (128, 512, 256)
-    assert resolved[("fused_dw", "2048x8192x49408", "int8")][1].startswith(
-        "cache:fused_dw/2048x8192x49408/int8/any")
-    # the other kernels keep their shipped or fixed tiles
-    assert at.lookup_tiles("fused_fwd", (7, 7, 7)) == at.DEFAULT_TILES
+    tiles = tiling.lookup_tiles("fused_dw", shape)
+    assert tiles == tiling.fused_tn_tiles(*shape)
+    assert tiling.RESOLVED[("fused_dw", shape)] == (tiles, "rule")
+    assert tiling.lookup_tiles("fused_fwd", (7, 7, 7)) == \
+        tiling.DEFAULT_TILES
+    assert tiling.RESOLVED[("fused_fwd", (7, 7, 7))] == \
+        (tiling.DEFAULT_TILES, "default")
+    assert tiling.lookup_tiles("q8_matmul", (7, 7, 7),
+                               default=(32, 128, 128)) == (32, 128, 128)
 
 
-def test_tile_candidates_respect_budget():
-    cands = at.tile_candidates(4096, 4096, 4096, kind="fused_tn")
-    assert cands
-    for bm, bn, bk in cands:
-        assert at.tile_vmem_bytes(bm, bn, bk, "fused_tn") \
-            <= at.VMEM_BUDGET_BYTES
-        assert bn % 128 == 0 and bk % 128 == 0
-    # small problems only get tiles that fit them (rounded up)
-    small = at.tile_candidates(16, 128, 128)
-    assert all(bm <= 32 for bm, _, _ in small)
+# Every (kernel, (M, K, N)) site the training cells' steps look up, with the
+# tiles and source the chip runs them at: each cell's tiles change only when
+# this table does.
+_RULE, _DEF = "rule", "default"
+CELL_SITES = {
+    "tx.train.bhq5": {
+        ("fused_dw", (512, 16384, 512)): ((512, 512, 1024), _RULE),
+        ("fused_dw", (512, 16384, 1024)): ((512, 1024, 256), _RULE),
+        ("fused_dw", (1024, 16384, 512)): ((1024, 512, 512), _RULE),
+        ("fused_dw", (512, 16384, 10240)): ((512, 1792, 128), _RULE),
+        ("fused_fwd", (16384, 512, 512)): ((128, 512, 512), _DEF),
+        ("fused_fwd", (16384, 512, 1024)): ((128, 512, 512), _DEF),
+        ("fused_fwd", (16384, 1024, 512)): ((128, 512, 512), _DEF),
+        ("fused_fwd", (16384, 512, 10240)): ((128, 512, 512), _DEF),
+        ("q8_matmul", (16384, 512, 512)): ((128, 512, 512), _DEF),
+        ("q8_matmul", (16384, 512, 1024)): ((128, 512, 512), _DEF),
+        ("q8_matmul", (16384, 1024, 512)): ((128, 512, 512), _DEF),
+        ("q8_matmul", (16384, 10240, 512)): ((128, 512, 512), _DEF),
+    },
+    "granite2b.train.bhq5": {
+        ("fused_dw", (2048, 8192, 2048)): ((2048, 512, 128), _RULE),
+        ("fused_dw", (2048, 8192, 512)): ((2048, 512, 128), _RULE),
+        ("fused_dw", (2048, 8192, 8192)): ((2048, 512, 128), _RULE),
+        ("fused_dw", (8192, 8192, 2048)): ((2048, 512, 128), _RULE),
+        ("fused_dw", (2048, 8192, 49408)): ((2048, 512, 128), _RULE),
+        ("fused_fwd", (8192, 2048, 2048)): ((128, 512, 512), _DEF),
+        ("fused_fwd", (8192, 2048, 512)): ((128, 512, 512), _DEF),
+        ("fused_fwd", (8192, 2048, 8192)): ((128, 512, 512), _DEF),
+        ("fused_fwd", (8192, 8192, 2048)): ((128, 512, 512), _DEF),
+        ("fused_fwd", (8192, 2048, 49408)): ((128, 512, 512), _DEF),
+        ("q8_matmul", (8192, 2048, 2048)): ((128, 512, 512), _DEF),
+        ("q8_matmul", (8192, 512, 2048)): ((128, 512, 512), _DEF),
+        ("q8_matmul", (8192, 2048, 8192)): ((128, 512, 512), _DEF),
+        ("q8_matmul", (8192, 8192, 2048)): ((128, 512, 512), _DEF),
+        ("q8_matmul", (8192, 49408, 2048)): ((128, 512, 512), _DEF),
+    },
+}
+_SITES = [(cell, kernel, shape, want)
+          for cell, sites in CELL_SITES.items()
+          for (kernel, shape), want in sites.items()]
 
 
-def test_vmem_accounting_matches_bench_row():
-    bm, bn, bk = 128, 512, 512
-    vecs = 4 * (2 * bm + 3 * bn)
-    q8 = bm * bk + bk * bn + 8 * bm * bn + vecs
-    assert at.q8_tile_vmem_bytes(bm, bn, bk) == q8
-    # the fused LHS tile holds f32 X + uint32 bits instead of int8 X
-    assert at.q8_tile_vmem_bytes(bm, bn, bk, fused=True) > q8
-    assert at.q8_tile_vmem_bytes(bm, bn, bk, fused=True) \
-        <= at.VMEM_BUDGET_BYTES
+@pytest.mark.parametrize(
+    "cell,kernel,shape,want", _SITES,
+    ids=[f"{c.split('.')[0]}-{k}-{'x'.join(map(str, s))}"
+         for c, k, s, _ in _SITES])
+def test_cell_site_tiles(cell, kernel, shape, want):
+    assert tiling.lookup_tiles(kernel, shape) == want[0]
+    assert tiling.RESOLVED[(kernel, shape)] == want
 
 
-@pytest.mark.slow
-def test_tune_sweep_plumbing(tmp_cache):
-    """End-to-end --tune on the tiny non-TPU shape: sweeps interpret-mode
-    Pallas kernels, persists winners, and lookup_tiles serves them."""
-    from benchmarks.bench_kernels import tune
-    winners = tune(log=lambda *a, **k: None, iters=1)
-    assert winners
-    at.reset_cache()
-    for key_name, tiles in winners.items():
-        kernel, shape = key_name.split("/")
-        dims = tuple(int(d) for d in shape.split("x"))
-        assert at.lookup_tiles(kernel, dims) == tuple(tiles)
+def _cell_engine(cell):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import model, train, train_granite
+    conf_name, traffic_name, cls = {
+        "tx.train.bhq5": ("statquant-tx", "train.b32s512", train.TrainCell),
+        "granite2b.train.bhq5": ("granite-3-2b", "train.b8s1024",
+                                 train_granite.GraniteCell),
+    }[cell]
+    return cls(model.load_json("configs", conf_name),
+               model.load_json("traffic", traffic_name)).engine
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SITES))
+def test_cell_step_looks_up_exactly_the_pinned_sites(cell):
+    """Tracing a cell's whole step (shapes only, nothing runs) looks up
+    exactly the pinned sites, at the pinned tiles."""
+    eng = _cell_engine(cell)
+    batch = jax.eval_shape(lambda: eng.batch_fn(0))
+    tiling.RESOLVED.clear()
+    jax.eval_shape(eng.step_fn, eng.abstract_state, batch)
+    assert tiling.RESOLVED == CELL_SITES[cell]

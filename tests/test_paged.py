@@ -106,7 +106,8 @@ def test_kv_gather_pallas_matches_xla(P, D, bm):
     scale = scale.at[0].set(0.0)
     table = jnp.asarray(rng.integers(0, n_pages, (B, nb)), jnp.int32)
     table = table.at[0, 0].set(0)
-    got = kv_gather_pages(codes, scale, zero, table, bm=bm, interpret=True)
+    kw = {} if bm is None else {"bm": bm}      # None: the wrapper's own bm
+    got = kv_gather_pages(codes, scale, zero, table, interpret=True, **kw)
     ref = kv_gather_pages_xla(codes, scale, zero, table)
     assert got.shape == (B, nb * P, D)
     assert bool(jnp.all(jnp.isfinite(got)))

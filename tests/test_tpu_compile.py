@@ -116,7 +116,7 @@ def test_fused_qboth_tn_matmul_granite(one_chip, n):
     rule's bn).  The shape rule puts all 2048 output rows in one stripe,
     so dY and its SR bits leave HBM once; the tiles fit the compiler's
     default scoped VMEM, and no operand is padded nor the output sliced."""
-    from repro.kernels.autotune import fused_tn_tiles
+    from repro.kernels.tiling import fused_tn_tiles
     from repro.kernels.fused_fqt import fused_qboth_tn_matmul
     m, k = 2048, 8192
     assert fused_tn_tiles(m, k, n)[0] == m
